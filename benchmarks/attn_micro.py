@@ -102,4 +102,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -73,5 +73,8 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(f"{r['name']},{r['us_per_call']:.2f},{r['derived']}")

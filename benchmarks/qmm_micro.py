@@ -152,5 +152,8 @@ def _dispatch_parity_rows(rng) -> list:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(f"{r['name']},{r['us_per_call']:.2f},{r['derived']}")

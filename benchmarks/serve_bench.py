@@ -37,8 +37,7 @@ def run_bench(args) -> dict:
     if args.smoke:
         cfg = smoke_variant(cfg)
     plan = parse_fault_plan(args.fault_plan)
-    params = Z.init_params(jax.random.PRNGKey(args.seed), cfg)
-    serving = Z.prepare_serving_params(params, cfg)
+    serving = Z.init_serving_params(jax.random.PRNGKey(args.seed), cfg)
     engine = ServeEngine(
         cfg,
         serving,
@@ -134,4 +133,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

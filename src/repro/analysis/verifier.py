@@ -44,7 +44,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 from repro.core import backend_registry, packing
@@ -159,7 +159,7 @@ class _TaintWalk:
         in_taints = [read(a) for a in eqn.invars]
         joined: Set[str] = set().union(*in_taints) if in_taints else set()
 
-        if prim == "pjit":
+        if prim == "jit":
             name = eqn.params.get("name", "")
             if name in PACK_NAMES:
                 for v in eqn.outvars:
@@ -630,7 +630,7 @@ def verify_arch(name: str) -> List[Finding]:
 
     key = _sds((2,), jnp.uint32)
     sp = jax.eval_shape(
-        lambda k: Z.prepare_serving_params(Z.init_params(k, cfg), cfg), key
+        lambda k: Z.init_serving_params(k, cfg), key
     )
     init_cache = jax.eval_shape(lambda: Z.init_cache(_B, _T, cfg))
     tokens = _sds((_B, _S), jnp.int32)
@@ -725,7 +725,7 @@ def verify_binary_attention() -> List[Finding]:
 
     key = _sds((2,), jnp.uint32)
     sp = jax.eval_shape(
-        lambda k: Z.prepare_serving_params(Z.init_params(k, cfg), cfg), key
+        lambda k: Z.init_serving_params(k, cfg), key
     )
     init_cache = jax.eval_shape(lambda: Z.init_cache(_B, _T, cfg))
 

@@ -52,6 +52,7 @@ __all__ = [
     "BACKENDS",  # deprecated dynamic view; use backend_registry.backend_names()
     "DEFAULT_BACKEND",
     "DEFAULT_SCORES_BACKEND",
+    "AutotuneError",
     "TuneKey",
     "TuneRecord",
     "AutotuneCache",
@@ -277,6 +278,27 @@ def make_scores_problem(key: TuneKey):
     return q_planes, k_planes
 
 
+class AutotuneError(RuntimeError):
+    """An autotune candidate raised on the chip."""
+
+
+def _candidate_errors_fatal() -> bool:
+    """On the chip every offered backend must run: a candidate that raises
+    is a broken kernel, and skipping it would quietly serve another
+    backend.  Off the chip (Pallas in interpret mode) a failing candidate
+    just loses."""
+    return jax.default_backend() == "tpu"
+
+
+def _candidate_failed(backend: str, key: TuneKey, err: Exception) -> None:
+    if _candidate_errors_fatal():
+        raise AutotuneError(
+            f"{key.family} backend {backend!r} raised while autotuning "
+            f"M={key.m} K={key.k} N={key.n} "
+            f"act_bits={key.act_bits} weight_bits={key.weight_bits}: {err!r}"
+        ) from err
+
+
 def _wallclock_timer(fn: Callable[[], object], *, warmup: int = 1, reps: int = 3) -> float:
     """Best-of-``reps`` wall-clock of ``fn`` in seconds, after compile warmup.
 
@@ -374,7 +396,8 @@ class AutotuneCache:
             )
             try:
                 timings[b] = self._timer(lambda c=call: c(xq, wq))
-            except Exception:  # noqa: BLE001 — a failing backend just loses
+            except Exception as e:  # noqa: BLE001 — fatal on the chip only
+                _candidate_failed(b, key, e)
                 continue
             self.timing_runs += 1
         if not timings:
@@ -396,7 +419,8 @@ class AutotuneCache:
             call = jax.jit(functools.partial(spec.run_scores, dh=key.k))
             try:
                 timings[b] = self._timer(lambda c=call: c(q_planes, k_planes))
-            except Exception:  # noqa: BLE001 — a failing backend just loses
+            except Exception as e:  # noqa: BLE001 — fatal on the chip only
+                _candidate_failed(b, key, e)
                 continue
             self.timing_runs += 1
         if not timings:

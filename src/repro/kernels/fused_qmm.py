@@ -43,12 +43,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.bitserial_qmm import check_block, plane_pair_popcounts
+
 __all__ = ["fused_qmm", "DEFAULT_BLOCK"]
 
-# bm, bn, bkw (words of 32 K-bits): 16 words = 512 logical K per step keeps
-# the padded-K floor low for ragged shapes while the plane tiles stay small
-# enough that an 8x8-plane worst case still fits VMEM comfortably.
-DEFAULT_BLOCK = (64, 128, 16)
+# bm, bn, bkw (words of 32 K-bits): the K-word block is the lane dim, so it
+# is 128 words (4096 logical K) or the whole Kw (bitserial_qmm.check_block).
+DEFAULT_BLOCK = (64, 128, 128)
 
 
 def _kernel(
@@ -63,8 +64,6 @@ def _kernel(
     row_ref,
     col_ref,
     *,
-    a_bits: int,
-    b_bits: int,
     bkw: int,
     k_logical: int,
 ):
@@ -76,43 +75,17 @@ def _kernel(
         row_ref[...] = jnp.zeros_like(row_ref)
         col_ref[...] = jnp.zeros_like(col_ref)
 
-    acc = jnp.zeros(acc_ref.shape, jnp.int32)
+    # rowsum(X) = sum_i 2^i * popcount(plane i), colsum(W) likewise — the
+    # same bits already in VMEM, no extra HBM traffic.
     row = jnp.zeros(row_ref.shape, jnp.int32)
+    for i in range(a_ref.shape[0]):
+        hits = jax.lax.population_count(a_ref[i]).astype(jnp.int32)
+        row = row + (jnp.sum(hits, axis=1, keepdims=True) << i)
     col = jnp.zeros(col_ref.shape, jnp.int32)
-    for i in range(a_bits):  # static unroll: the bit-serial schedule
-        a_i = a_ref[i]  # (bm, bkw) uint32
-        # rowsum(X) = sum_i 2^i * popcount(plane i) — same bits, no extra HBM.
-        row = row + (
-            jnp.sum(
-                jax.lax.population_count(a_i).astype(jnp.int32),
-                axis=1,
-                keepdims=True,
-            )
-            << i
-        )
-        for j in range(b_bits):
-            b_j = b_ref[j]  # (bkw, bn) uint32
-            if i == 0:
-                col = col + (
-                    jnp.sum(
-                        jax.lax.population_count(b_j).astype(jnp.int32),
-                        axis=0,
-                        keepdims=True,
-                    )
-                    << j
-                )
-
-            def word_step(w, inner, a_i=a_i, b_j=b_j):
-                aw = jax.lax.dynamic_slice_in_dim(a_i, w, 1, axis=1)
-                bw = jax.lax.dynamic_slice_in_dim(b_j, w, 1, axis=0)
-                joint = jnp.bitwise_and(aw, bw)
-                return inner + jax.lax.population_count(joint).astype(jnp.int32)
-
-            part = jax.lax.fori_loop(
-                0, bkw, word_step, jnp.zeros(acc_ref.shape, jnp.int32)
-            )
-            acc = acc + (part << (i + j))
-    acc_ref[...] += acc
+    for j in range(b_ref.shape[0]):
+        hits = jax.lax.population_count(b_ref[j]).astype(jnp.int32)
+        col = col + (jnp.sum(hits, axis=0, keepdims=True) << j)
+    acc_ref[...] = plane_pair_popcounts(a_ref, b_ref, acc_ref[...], bkw=bkw)
     row_ref[...] += row
     col_ref[...] += col
 
@@ -155,8 +128,9 @@ def fused_qmm(
       w_scale / w_offset: fp32 ``(1, N)`` per-column affine coefficients.
       k: *logical* K (pre-padding) — the constant term uses the true
         reduction length; padded zero bits contribute nothing elsewhere.
-      block: ``(bm, bn, bkw)``; all operand dims must be pre-padded to
-        multiples (``repro.kernels.ops.qmm_fused`` handles padding).
+      block: ``(bm, bn, bkw)``; ``bkw`` is clamped to ``Kw``, and all operand
+        dims must be pre-padded to multiples (``repro.kernels.ops.qmm_fused``
+        handles padding).
       interpret: CPU validation mode.
 
     Returns:
@@ -171,15 +145,13 @@ def fused_qmm(
         raise ValueError(f"activation coefficients must be ({m}, 1)")
     if w_scale.shape != (1, n) or w_offset.shape != (1, n):
         raise ValueError(f"weight coefficients must be (1, {n})")
-    bm, bn, bkw = block
-    if m % bm or n % bn or kw % bkw:
-        raise ValueError(f"shapes ({m},{kw},{n}) not multiples of block {block}")
+    bm, bn, bkw = check_block(m, n, kw, block)
 
     grid = (m // bm, n // bn, kw // bkw)
     coeff = jnp.float32
     return pl.pallas_call(
         functools.partial(
-            _kernel, a_bits=a_bits, b_bits=b_bits, bkw=bkw, k_logical=int(k)
+            _kernel, bkw=bkw, k_logical=int(k)
         ),
         grid=grid,
         in_specs=[

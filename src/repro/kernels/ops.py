@@ -51,6 +51,15 @@ def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _fit_block(block, m: int, kw: int):
+    """Shrink a ``(bm, bn, bkw)`` popcount-kernel block to the problem: ``bm``
+    to M rounded up to a sublane multiple (decode's M=8 is not padded to 64),
+    ``bkw`` to the whole Kw when it fits in one block.  Callers then pad Kw
+    to a multiple of ``bkw`` — the whole Kw or 128-word lane-aligned slabs."""
+    bm, bn, bkw = block
+    return min(bm, -(-m // 8) * 8), bn, min(bkw, kw)
+
+
 def binary_qmm_int(
     a: jax.Array,
     w_packed: jax.Array,
@@ -85,9 +94,10 @@ def popcount_qmm_int(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Binary x binary over packed operands with auto-padding (M, N, Kw)."""
-    bm, bn, bkw = block
     m, kw = a_packed.shape
     n = b_packed.shape[1]
+    block = _fit_block(block, m, kw)
+    bm, bn, bkw = block
     a_p = _pad_to(_pad_to(a_packed, 0, bm), 1, bkw)
     b_p = _pad_to(_pad_to(b_packed, 0, a_p.shape[1]), 1, bn)
     out = _pq.popcount_qmm(a_p, b_p, block=block, interpret=_auto_interpret(interpret))
@@ -102,9 +112,10 @@ def bitserial_qmm_int(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Multi-bit act x act from packed planes with auto-padding."""
-    bm, bn, bkw = block
     _, m, kw = a_planes.shape
     n = b_planes.shape[2]
+    block = _fit_block(block, m, kw)
+    bm, bn, bkw = block
     a_p = _pad_to(_pad_to(a_planes, 1, bm), 2, bkw)
     b_p = _pad_to(_pad_to(b_planes, 1, a_p.shape[2]), 2, bn)
     out = _bs.bitserial_qmm(a_p, b_p, block=block, interpret=_auto_interpret(interpret))
@@ -215,6 +226,7 @@ def qmm_fused(
     w_scale = jnp.broadcast_to(jnp.asarray(w.scale, f32), (1, n))
     w_off = jnp.broadcast_to(jnp.asarray(w.offset, f32), (1, n))
 
+    block = _fit_block(block, m, a_planes.shape[2])
     bm, bn, bkw = block
     a_p = _pad_to(_pad_to(a_planes, 1, bm), 2, bkw)
     b_p = _pad_to(_pad_to(b_planes, 1, a_p.shape[2]), 2, bn)

@@ -3,30 +3,21 @@
 This is BETA's dot-product unit (Fig. 3b) transcribed to the TPU *vector*
 unit: both operands stay bit-packed in uint32 lanes; a PE-sequence step is
 ``and`` + ``population_count`` on whole VREGs (32 binary MACs per lane-op),
-and the compressor-tree is a log-depth integer tree-sum over the word axis,
-with the int32 accumulator tile carried across the K-grid in VMEM (the
-compressor-tree *loop*).
+and the compressor-tree is the int32 accumulator tile carried across the
+K-grid in VMEM (the compressor-tree *loop*).
 
 With the unified unsigned-mantissa form ({0,1} rather than +-1), XNOR-
 popcount becomes AND-popcount; the affine flow-abstraction epilogue absorbs
 the difference — one datapath for both operand kinds, like BETA.
 
-When to use which kernel (DESIGN.md §Perf napkin math): each VPU lane-op
-does 32 1-bit MACs; the MXU int8 path does 1 MAC/lane but on the 128x128
-systolic array at ~2x bf16 clocking.  On v5e the MXU path wins for K
-greater than ~256 at bm,bn >= 128; the popcount path wins for skinny/small
-QMMs (edge regime, exactly the paper's target) and when int8 unpack traffic
-dominates.  Both are exposed; benchmarks/qmm_micro quantifies the crossover.
+W1A1 is the one-plane case of the bit-serial schedule, so this kernel is
+``bitserial_qmm`` with a single plane per operand: the same word loop
+(``bitserial_qmm.plane_pair_popcounts``) and the same lane-aligned blocks.
 
 Blocking: grid = (M/bm, N/bn, Kw/bkw), K innermost.
   A  (bm, bkw)  uint32  — packed left mantissas (K packed along -1)
   B  (bkw, bn)  uint32  — packed right mantissas (K packed along -2)
   O  (bm, bn)   int32
-
-VMEM @ defaults (64, 128, 64): A 16 KiB + B 32 KiB + joint (64,128,64) int32
-2 MiB... the joint broadcast is avoided by looping words in VREG-sized
-chunks; the body below trades a small fori_loop over the word axis for a
-bounded footprint (acc + 2 operand tiles ~ 100 KiB).
 """
 
 from __future__ import annotations
@@ -34,34 +25,10 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.bitserial_qmm import DEFAULT_BLOCK, bitserial_qmm
 
 __all__ = ["popcount_qmm", "DEFAULT_BLOCK"]
-
-DEFAULT_BLOCK = (64, 128, 64)  # bm, bn, bkw (bkw in 32-bit WORDS of K)
-
-
-def _kernel(a_ref, b_ref, o_ref, *, bkw: int):
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a = a_ref[...]  # (bm, bkw) uint32
-    b = b_ref[...]  # (bkw, bn) uint32
-
-    def word_step(w, acc):
-        # One unfolded PE-sequence step: 32 binary MACs per (m, n) lane pair.
-        aw = jax.lax.dynamic_slice_in_dim(a, w, 1, axis=1)  # (bm, 1)
-        bw = jax.lax.dynamic_slice_in_dim(b, w, 1, axis=0)  # (1, bn)
-        joint = jnp.bitwise_and(aw, bw)  # broadcast -> (bm, bn)
-        return acc + jax.lax.population_count(joint).astype(jnp.int32)
-
-    acc = jnp.zeros(o_ref.shape, jnp.int32)
-    acc = jax.lax.fori_loop(0, bkw, word_step, acc)
-    o_ref[...] += acc
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -77,29 +44,14 @@ def popcount_qmm(
     Args:
       a_packed: uint32 ``(M, Kw)``; K bit-packed along the last axis.
       b_packed: uint32 ``(Kw, N)``; K bit-packed along the first axis.
-      block: (bm, bn, bkw) tile sizes; Kw (words) must divide by bkw.
+      block: (bm, bn, bkw) tile sizes; ``bkw`` is clamped to ``Kw``.
       interpret: CPU validation mode.
 
     Returns:
       int32 ``(M, N)`` — popcount-accumulated binary dot products.
     """
-    m, kw = a_packed.shape
-    kw2, n = b_packed.shape
-    if kw != kw2:
+    if a_packed.shape[1] != b_packed.shape[0]:
         raise ValueError(f"packed-K mismatch: {a_packed.shape} vs {b_packed.shape}")
-    bm, bn, bkw = block
-    if m % bm or n % bn or kw % bkw:
-        raise ValueError(f"shapes ({m},{kw},{n}) not multiples of block {block}")
-
-    grid = (m // bm, n // bn, kw // bkw)
-    return pl.pallas_call(
-        functools.partial(_kernel, bkw=bkw),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bkw), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bkw, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
-    )(a_packed, b_packed)
+    return bitserial_qmm(
+        a_packed[None], b_packed[None], block=block, interpret=interpret
+    )

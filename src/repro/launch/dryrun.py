@@ -218,8 +218,7 @@ def lower_period_body(cfg: ArchConfig, shape: InputShape, mesh) -> dict:
 
 def _abstract_params(cfg: ArchConfig, serving: bool):
     def build(key):
-        p = Z.init_params(key, cfg)
-        return Z.prepare_serving_params(p, cfg) if serving else p
+        return Z.init_serving_params(key, cfg) if serving else Z.init_params(key, cfg)
 
     return jax.eval_shape(build, jax.random.PRNGKey(0))
 

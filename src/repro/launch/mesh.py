@@ -13,30 +13,13 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "abstract_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def abstract_mesh(shape, axes):
-    """Device-free mesh for spec-legality checks, across jax API versions.
-
-    jax >= 0.5 takes ``AbstractMesh(shape, axis_names)``; 0.4.x takes a
-    single tuple of ``(name, size)`` pairs.
-    """
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,9 +3,13 @@
   PYTHONPATH=src python -m repro.launch.serve --arch granite-8b --smoke \
       --requests 8 --max-new 16
 
-Initializes a model, runs the offline weight pipeline (binarize -> bit-pack
--> colsum fold, the paper's 'performed offline' step), and serves a queue of
-requests through the slot-managed continuous-batching engine.
+Initializes a model from ``--seed`` and runs the offline weight pipeline
+(binarize -> bit-pack -> colsum fold, the paper's 'performed offline' step)
+one layer at a time (``model_zoo.init_serving_params``), so a full-size
+model such as granite-8b fits one TPU v5e without ``--smoke``.  Then it
+serves a queue of requests through the slot-managed continuous-batching
+engine.  The process exits 1 if any request ends "failed" and no
+``--fault-plan`` was given.
 
 Two request sources:
 
@@ -38,6 +42,7 @@ token-for-token identical to an uninterrupted run::
 """
 
 import argparse
+import sys
 import time
 
 import jax
@@ -98,14 +103,16 @@ def main() -> None:
         cfg = smoke_variant(cfg)
 
     rng = np.random.default_rng(args.seed)
-    params = Z.init_params(jax.random.PRNGKey(args.seed), cfg)
-    serving = Z.prepare_serving_params(params, cfg)
+    key = jax.random.PRNGKey(args.seed)
+    serving = Z.init_serving_params(key, cfg)
 
-    # packed-weight footprint accounting (the paper's compression headline)
+    # packed-weight footprint accounting (the paper's compression headline);
+    # the fp32 latent model is only ever a shape here
     def nbytes(t):
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
 
-    full, packed = nbytes(params), nbytes(serving)
+    full = nbytes(jax.eval_shape(lambda k: Z.init_params(k, cfg), key))
+    packed = nbytes(serving)
     print(
         f"[serve] weights: fp32 latent {full/1e6:.1f} MB -> packed {packed/1e6:.1f} MB"
         f" ({full/packed:.1f}x)"
@@ -162,6 +169,8 @@ def main() -> None:
           f"({total_tokens/dt:.1f} tok/s incl. compile)")
     for i, r in enumerate(done[:4]):
         print(f"  req{i}: prompt[:4]={np.asarray(r.prompt)[:4].tolist()} -> out[:8]={r.output[:8]}")
+    print(f"[serve] failed: {len(engine.last_failed)} of {len(done)} requests "
+          f"{engine.last_failed}")
     if args.bench_out:
         summary = summarize_bench(
             done, dt,
@@ -174,7 +183,12 @@ def main() -> None:
         print(f"[serve] bench summary -> {args.bench_out} "
               f"(rps={summary['rps']:.2f}, p50={summary['p50_ms']:.1f}ms, "
               f"p99={summary['p99_ms']:.1f}ms)")
+    if engine.last_failed and args.fault_plan is None:
+        sys.exit(1)
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

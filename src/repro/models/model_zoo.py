@@ -4,6 +4,7 @@ Entry points (all pure functions of explicit params — pjit-ready):
 
 * ``init_params(key, cfg)``            — training params (latent fp32)
 * ``prepare_serving_params(params)``   — offline: binarize, bit-pack, colsums
+* ``init_serving_params(key, cfg)``    — both of the above, one layer at a time
 * ``loss_fn(params, batch, cfg, mode)``— LM loss (+ MoE aux, + MTP)
 * ``forward_logits(...)``              — full-sequence logits
 * ``init_cache(batch, max_len, cfg)``  — serving caches (quantized KV)
@@ -16,6 +17,7 @@ frame/patch embeddings; the transformer backbone is the real deliverable.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -30,6 +32,7 @@ from repro.models import transformer as T
 __all__ = [
     "init_params",
     "prepare_serving_params",
+    "init_serving_params",
     "loss_fn",
     "forward_logits",
     "init_cache",
@@ -46,7 +49,9 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def init_params(key, cfg: ArchConfig) -> dict:
+def init_params(key, cfg: ArchConfig, block_fn=None) -> dict:
+    """Latent fp32 params.  ``block_fn`` maps each block as it is built
+    (``transformer.init_stack``); ``init_serving_params`` packs with it."""
     ks = jax.random.split(key, 8)
     d = cfg.d_model
     p: dict = {
@@ -59,7 +64,7 @@ def init_params(key, cfg: ArchConfig) -> dict:
             jax.random.normal(ks[1], (cfg.vocab_size, d), jnp.float32) * 0.02
         )
     cross = cfg.encoder is not None and cfg.encoder.n_layers > 0
-    p["stack"] = T.init_stack(ks[2], cfg, cross=cross)
+    p["stack"] = T.init_stack(ks[2], cfg, cross=cross, block_fn=block_fn)
 
     if cfg.encoder is not None:
         enc: dict = {}
@@ -68,7 +73,7 @@ def init_params(key, cfg: ArchConfig) -> dict:
         if cfg.encoder.n_layers:
             # a small bidirectional transformer on top of the stub (whisper)
             enc_cfg = _encoder_cfg(cfg)
-            enc["stack"] = T.init_stack(ks[4], enc_cfg)
+            enc["stack"] = T.init_stack(ks[4], enc_cfg, block_fn=block_fn)
             enc["final_norm"] = jnp.zeros((d,), jnp.float32)
         p["encoder"] = enc
 
@@ -141,6 +146,22 @@ def prepare_serving_params(params: dict, cfg: ArchConfig):
         return node
 
     return walk(params, (), False)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def init_serving_params(key, cfg: ArchConfig) -> dict:
+    """``prepare_serving_params(init_params(key, cfg), cfg)`` without the fp32
+    latent model ever being whole on the device.
+
+    Every block is packed as soon as it is initialised (one scanned layer
+    per ``lax.map`` step), so device memory holds the packed model plus one
+    layer's latent weights — for granite-8b 1.4 GB instead of 32 GB.
+    Packing is idempotent on packed blocks, so the outer
+    ``prepare_serving_params`` only converts the embeddings.  Bit-identical
+    to the two-step path under ``jit``.
+    """
+    pack = functools.partial(prepare_serving_params, cfg=cfg)
+    return prepare_serving_params(init_params(key, cfg, block_fn=pack), cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ mapped to pre-norm — systems-equivalent, noted in DESIGN.md).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,15 +138,23 @@ def block_apply(
 # ---------------------------------------------------------------------------
 
 
-def init_stack(key, cfg: ArchConfig, cross: bool = False) -> dict:
+def init_stack(
+    key, cfg: ArchConfig, cross: bool = False, block_fn: Optional[Callable] = None
+) -> dict:
     """Params: {'prefix': [block...], 'period': [stacked-block...]}.
 
     Period params are stacked along axis 0 with length ``n_periods`` (one
     entry per scan step), independently for each position in the period.
+
+    Period blocks are built one layer at a time under ``lax.map``.
+    ``block_fn``, when given, maps every block as soon as it is initialised
+    (e.g. to its packed serving form), so inside ``jit`` only one layer's
+    latent weights exist at once.
     """
     keys = jax.random.split(key, len(cfg.prefix_layers) + 1)
+    fn = block_fn or (lambda blk: blk)
     prefix = [
-        init_block(keys[i], cfg, kind, cross)
+        fn(init_block(keys[i], cfg, kind, cross))
         for i, kind in enumerate(cfg.prefix_layers)
     ]
     period = []
@@ -154,8 +162,11 @@ def init_stack(key, cfg: ArchConfig, cross: bool = False) -> dict:
         pkeys = jax.random.split(keys[-1], len(cfg.pattern_period))
         for j, kind in enumerate(cfg.pattern_period):
             reps = jax.random.split(pkeys[j], cfg.n_periods)
-            stacked = jax.vmap(lambda k: init_block(k, cfg, kind, cross))(reps)
-            period.append(stacked)
+
+            def one(k, kind=kind):
+                return fn(init_block(k, cfg, kind, cross))
+
+            period.append(jax.lax.map(one, reps))
     return {"prefix": prefix, "period": period}
 
 

@@ -68,7 +68,7 @@ __all__ = [
 
 def serving_params_shardings(cfg: ArchConfig, mesh: Mesh):
     tmpl = jax.eval_shape(
-        lambda k: Z.prepare_serving_params(Z.init_params(k, cfg), cfg),
+        lambda k: Z.init_serving_params(k, cfg),
         jax.random.PRNGKey(0),
     )
     return SH.params_shardings(tmpl, mesh), tmpl
@@ -260,11 +260,11 @@ class ServeEngine:
     * A failed snapshot write is an event, not an outage: the engine keeps
       serving and tries again at the next boundary.
 
-    The event trace of the last ``run``/``resume`` is kept on
-    ``last_events`` (kinds: admit/prefill/insert/decode_tick/finish/reset
-    plus step_fault/retry_tick/backend_fault/demote/nan_logits/requeue/
-    request_failed/prefill_fault/deadline_miss/snapshot/snapshot_failed/
-    resume).
+    The rids of the requests the last ``run``/``resume`` ended "failed" are
+    kept on ``last_failed``; its event trace on ``last_events`` (kinds:
+    admit/prefill/insert/decode_tick/finish/reset plus step_fault/
+    retry_tick/backend_fault/demote/nan_logits/requeue/request_failed/
+    prefill_fault/deadline_miss/snapshot/snapshot_failed/resume).
     """
 
     def __init__(
@@ -314,6 +314,8 @@ class ServeEngine:
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
         self.mesh = mesh
         self.last_events: List[Dict] = []
+        # rids of the requests the last run/resume terminally failed
+        self.last_failed: List[int] = []
         self.autotune_cache_path = autotune_cache_path
         if autotune_cache_path and os.path.exists(autotune_cache_path):
             dispatch.get_cache().load(autotune_cache_path)
@@ -605,6 +607,7 @@ class ServeEngine:
                     )
                 st.snaps += 1
 
+        self.last_failed = [r.rid for r in st.requests if r.state == STATE_FAILED]
         if self.autotune_cache_path:
             dispatch.get_cache().save(self.autotune_cache_path)
 
@@ -769,6 +772,8 @@ def serve_sequential(
     with the engine so sampling (not just greedy argmax) is comparable.
     Fault-free and deadline-blind by construction — it defines the token
     sequences the fault-tolerant engine must reproduce."""
+    # one compile for every token (an eager call retraces the layer scan)
+    decode = jax.jit(lambda p, t, c: Z.decode_step(p, t, cfg, c))
     for rid, r in enumerate(requests):
         if len(r.prompt) + r.max_new_tokens > max_len:
             raise ValueError("request exceeds max_len")
@@ -780,9 +785,7 @@ def serve_sequential(
         tok = _sample(np.asarray(logits)[0], r.temperature, rng)
         r.output = [tok]
         while len(r.output) < r.max_new_tokens:
-            logits, cache = Z.decode_step(
-                params, jnp.asarray([tok], np.int32), cfg, cache
-            )
+            logits, cache = decode(params, jnp.asarray([tok], np.int32), cache)
             tok = _sample(np.asarray(logits)[0], r.temperature, rng)
             r.output.append(tok)
         r.state = STATE_OK

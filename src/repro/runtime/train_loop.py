@@ -221,8 +221,6 @@ def make_compressed_dp_step(
     Wire traffic: 4x fewer gradient bytes than fp32 psum (see
     benchmarks/compression_bench.py for the measured payload accounting).
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = SH.data_axes(mesh)
     loss_fn = functools.partial(_loss, cfg=cfg, aux_weight=tcfg.aux_weight)
 
@@ -244,12 +242,12 @@ def make_compressed_dp_step(
             return params2, opt2, err_state, metrics
 
         batch_spec = jax.tree.map(lambda _: P(axes, *([None])), batch)
-        return shard_map(
+        return jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), P(), P(), batch_spec),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, err_state, batch)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))

@@ -126,3 +126,21 @@ def test_quantized_serve_close_to_float(built, name):
     # datapaths differ in quantizer granularity; demand ranking overlap
     overlap = len(set(map(int, t)) & set(map(int, s)))
     assert overlap >= 2, f"serve/train top-5 overlap too low: {overlap}"
+
+
+@pytest.mark.parametrize(
+    "name", ["granite-8b", "deepseek-v2-lite-16b", "whisper-tiny", "mamba2-130m"]
+)
+def test_init_serving_params_matches_two_step_path(name):
+    """Packing each layer as it is initialised gives, bit for bit, what
+    packing the whole latent model gives (both under jit)."""
+    import numpy as np
+
+    cfg = smoke_variant(get_config(name))
+    key = jax.random.PRNGKey(7)
+    want = jax.jit(lambda k: Z.prepare_serving_params(Z.init_params(k, cfg), cfg))(key)
+    got = Z.init_serving_params(key, cfg)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -58,11 +58,12 @@ def test_compressed_psum_multidevice_parity():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.optim import compression as C
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh(
+            (8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+        )
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.standard_normal((8, 128)).astype(np.float32))
 
@@ -73,9 +74,9 @@ def test_compressed_psum_multidevice_parity():
             exact, _ = C.compressed_psum({"g": g}, err, "data", enabled=False)
             return avg["g"][None], exact["g"][None]
 
-        avg, exact = shard_map(
+        avg, exact = jax.shard_map(
             f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-            check_rep=False
+            check_vma=False
         )(g)
         a, e = np.asarray(avg[0]), np.asarray(exact[0])
         rel = np.abs(a - e).max() / (np.abs(e).max() + 1e-9)

@@ -216,6 +216,21 @@ def test_failed_tuning_falls_back_but_is_never_persisted(tmp_path):
     assert fresh.choose(8, 64, 32, 1, 1) == "popcount"  # re-timed, not pinned
 
 
+@pytest.mark.parametrize("family", ["qmm", "scores"])
+def test_failing_candidate_is_fatal_on_the_chip(monkeypatch, family):
+    """On the TPU a candidate that raises is a broken kernel: the error
+    names the backend and the shape instead of the backend quietly losing."""
+    monkeypatch.setattr(dispatch, "_candidate_errors_fatal", lambda: True)
+
+    def exploding_timer(fn):
+        raise RuntimeError("kernel refused")
+
+    cache = dispatch.AutotuneCache(timer=exploding_timer)
+    with pytest.raises(dispatch.AutotuneError, match=r"backend '\w+'.*M=8 K=64 N=32"):
+        cache.choose(8, 64, 32, 1, 1, family=family)
+    assert not cache.entries
+
+
 def test_load_skips_unknown_backends(tmp_path):
     path = str(tmp_path / "autotune.json")
     cache = dispatch.AutotuneCache(timer=seq_timer([1.0] * 10))

@@ -23,8 +23,9 @@ from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(17)
 
-# tile-aligned, ragged-everything, tiny, and mid-size K-ragged
-SHAPES = [(64, 512, 128), (37, 300, 45), (5, 64, 3), (16, 96, 24)]
+# tile-aligned, ragged-everything, tiny, mid-size K-ragged, and several
+# 128-word K blocks (Kw = 141 words padded to 256)
+SHAPES = [(64, 512, 128), (37, 300, 45), (5, 64, 3), (16, 96, 24), (8, 4500, 16)]
 # W1A1, W1A8, W1A4, A8xA8, A4xA4
 PRECISIONS = [(1, 1), (8, 1), (4, 1), (8, 8), (4, 4)]
 

@@ -82,6 +82,32 @@ def test_popcount_qmm_ragged_via_ops(m, k, n):
     np.testing.assert_array_equal(np.asarray(out), a @ b)
 
 
+def test_popcount_qmm_several_k_blocks_via_ops():
+    """Kw > 128 words: padded to 128-word lane-aligned blocks, accumulated
+    across the K grid (the granite-8b ffn.down regime, K=14336)."""
+    m, k, n = 9, 8320, 40  # 260 words -> 3 blocks of 128
+    a = RNG.integers(0, 2, size=(m, k)).astype(np.int32)
+    b = RNG.integers(0, 2, size=(k, n)).astype(np.int32)
+    ap = packing.pack_bits(jnp.asarray(a), 1, axis=-1)
+    bp = packing.pack_bits(jnp.asarray(b), 1, axis=0)
+    out = ops.popcount_qmm_int(ap, bp, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), a @ b)
+
+
+@pytest.mark.parametrize(
+    "shape,block",
+    [((8, 256, 128), (8, 128, 64)),  # bkw neither 128-aligned nor whole Kw
+     ((16, 64, 128), (12, 128, 64)),  # bm not a sublane multiple
+     ((8, 64, 256), (8, 64, 64))],  # bn not a lane multiple
+)
+def test_popcount_qmm_rejects_blocks_mosaic_cannot_tile(shape, block):
+    m, kw, n = shape
+    a = jnp.zeros((m, kw), jnp.uint32)
+    b = jnp.zeros((kw, n), jnp.uint32)
+    with pytest.raises(ValueError):
+        PK.popcount_qmm(a, b, block=block, interpret=True)
+
+
 # ---------------------------------------------------------------------------
 # bitserial_qmm: multi-bit act x act
 # ---------------------------------------------------------------------------
@@ -96,6 +122,16 @@ def test_bitserial_qmm_block_aligned(a_bits, b_bits):
     out = BS.bitserial_qmm(apl, bpl, interpret=True)
     expect = ref.bitserial_qmm_ref(apl, bpl, k)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+    np.testing.assert_array_equal(np.asarray(out), a @ b)
+
+
+def test_bitserial_qmm_several_k_blocks_via_ops():
+    m, k, n = 8, 4500, 24  # 141 words -> 2 blocks of 128
+    a = RNG.integers(0, 16, size=(m, k)).astype(np.int32)
+    b = RNG.integers(0, 4, size=(k, n)).astype(np.int32)
+    apl = packing.pack_bitplanes(jnp.asarray(a), 4, axis=-1)
+    bpl = packing.pack_bitplanes(jnp.asarray(b), 2, axis=-2)
+    out = ops.bitserial_qmm_int(apl, bpl, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), a @ b)
 
 

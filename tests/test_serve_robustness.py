@@ -137,10 +137,12 @@ def test_retry_exhaustion_is_terminal_but_engine_survives(model):
     got = eng.run(_requests(model[0], n=3))
     assert all(r.state == STATE_FAILED for r in got)
     assert all(r.retries == eng.max_retries + 1 for r in got)
+    assert eng.last_failed == [r.rid for r in got]
     # engine object still healthy: a fresh fault-free engine semantics check
     clean = _engine(model)
     again = clean.run(_requests(model[0], n=3))
     assert all(r.state == STATE_OK for r in again)
+    assert clean.last_failed == []
     _assert_token_identical(again, _oracle(model, n=3))
 
 
@@ -395,3 +397,26 @@ def test_sigkill_mid_batch_then_resume_matches_oracle(model, tmp_path):
             f"rid={got.rid}: resumed run diverged from oracle after SIGKILL: "
             f"{got.output} != {exp.output}"
         )
+
+
+def test_serve_cli_exits_nonzero_when_requests_fail(monkeypatch, capsys):
+    """``launch/serve.py`` prints the failed count on a line of its own; a
+    failed request is a failed run unless the failures were injected."""
+    import json
+    import sys
+
+    from repro.launch import serve
+
+    plan = FaultPlan(decode_fail_attempts=tuple(range(100)))
+    argv = ["serve", "--arch", "granite-8b", "--smoke", "--requests", "2",
+            "--prompt-len", "4", "--max-new", "2", "--max-len", "16"]
+    monkeypatch.setattr(sys, "argv", argv + ["--fault-plan", json.dumps(plan.to_dict())])
+    serve.main()  # injected: reported, exit 0
+    assert "[serve] failed: 2 of 2 requests" in capsys.readouterr().out
+
+    monkeypatch.setattr(sys, "argv", argv)
+    monkeypatch.setattr(serve, "parse_fault_plan", lambda _: plan)
+    with pytest.raises(SystemExit) as exc:
+        serve.main()  # same failures, none asked for: the run fails
+    assert exc.value.code == 1
+    assert "[serve] failed: 2 of 2 requests" in capsys.readouterr().out

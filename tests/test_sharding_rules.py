@@ -11,9 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import AbstractMesh, Mesh
 
-from repro.launch.mesh import abstract_mesh
 
 from repro.configs import ASSIGNED, get_config
 from repro.models import model_zoo as Z
@@ -24,7 +23,7 @@ from repro.runtime import sharding as SH
 def mesh():
     dev = np.array(jax.devices()[:1] * 1)
     # spec-level tests only need axis names/sizes; build an abstract mesh
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_tree(tree, shardings, mesh):
@@ -79,7 +78,7 @@ def test_row_parallel_never_splits_packed_words():
     """Row-parallel packed weights shard the WORD axis; 16-way sharding of
     K/32 words requires K % (32*16) == 0 — check the real archs satisfy it
     or the rule falls back to replication."""
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in ASSIGNED:
         cfg = get_config(arch)
         params = jax.eval_shape(
@@ -97,12 +96,12 @@ def test_row_parallel_never_splits_packed_words():
 
 
 def test_long500k_batch1_uses_sequence_sharding():
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     spec = SH.logical_batch_spec(1, 524288, mesh)
     assert spec == jax.sharding.PartitionSpec(None, "data")
 
 
 def test_train4k_batch_sharded_over_pods_and_data():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     spec = SH.logical_batch_spec(256, 4096, mesh)
     assert spec == jax.sharding.PartitionSpec(("pod", "data"), None)
