@@ -1,0 +1,6 @@
+"""Output tokens emitted in the window over the window's length."""
+
+
+def read(run):
+    n = sum(1 for r in run.requests for t in r.token_times if run.in_window(t))
+    return n / run.seconds if n else None

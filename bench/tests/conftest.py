@@ -1,0 +1,9 @@
+"""Import paths for the benchmark's CPU tests: the checkout and its ``src/``."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
