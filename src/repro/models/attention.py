@@ -550,9 +550,6 @@ def attention(
     """
     quant = cfg.quant
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    b, s, _ = x.shape
-    causal = cfg.causal if causal is None else causal
-    window = cfg.window_size if kind == "l" else 0
 
     q = _split_heads(L.qlinear(p["q"], x, quant, mode, name="attn.q"), h, dh)
     if kv_override is None:
@@ -560,6 +557,27 @@ def attention(
         v = _split_heads(L.qlinear(p["v"], x, quant, mode, name="attn.v"), kvh, dh)
     else:
         k, v = kv_override
+
+    with jax.named_scope("attn.core"):
+        ctx, new_cache = _attention_core(
+            p, x, q, k, v, cfg, kind, mode, positions, cache, kv_override, causal
+        )
+    out = L.qlinear(
+        p["o"], _merge_heads(ctx).astype(x.dtype), quant, mode, name="attn.o"
+    )
+    return out, new_cache
+
+
+def _attention_core(p, x, q, k, v, cfg, kind, mode, positions, cache, kv_override, causal):
+    """Everything between the k/v projections and ``attn.o``: norms, rope,
+    the cache write (``attn.cache``), the scores with mask and scale
+    (``attn.qk``) and softmax with AV (``attn.av``).  Returns the context
+    (B, S, H, dh) and the updated cache."""
+    quant = cfg.quant
+    h, dh = cfg.n_heads, cfg.d_head
+    b, s, _ = x.shape
+    causal = cfg.causal if causal is None else causal
+    window = cfg.window_size if kind == "l" else 0
 
     if cfg.qk_norm:
         q = L.rmsnorm(p["q_norm"], q, cfg.norm_eps)
@@ -607,123 +625,126 @@ def attention(
         # (training, or serving prefill from an empty cache)
         sdt = jnp.bfloat16 if cfg.attn_scores_dtype == "bf16" else jnp.float32
         expand = cfg.gqa_mode == "expand"
-        if use_int and use_binary:
-            kq = _binarize_rows(k)
-            # cache affines are per-row (B,) — drop the keepdims axes
-            k_sc = jnp.reshape(kq.scale, (b,))
-            k_off = jnp.reshape(kq.offset, (b,))
-            k_m = packing.pack_bits(kq.mantissa.astype(jnp.uint32), 1, axis=-1)
-            v_sc, v_off = _calibrate_rows(v)
-            v_m = _quantize_to_cache(v, v_sc, v_off)
-            scores = _scores_binary(
-                q, k_m.transpose(0, 2, 1, 3), k_sc, k_off, dh, "attn.qk", qk_backend
-            )
-        elif use_int:
-            k_sc, k_off = _calibrate_rows(k)
-            v_sc, v_off = _calibrate_rows(v)
-            k_m = _quantize_to_cache(k, k_sc, k_off)
-            v_m = _quantize_to_cache(v, v_sc, v_off)
-            k_s = _gqa_expand(k_m, h) if expand else k_m
-            scores = _scores_int(q, k_s, k_sc, k_off, quant.attn_act_bits, qk_backend)
-        else:
-            qf = q
-            kf = k
-            if mode == "train" and quant.enabled and quant.quantize_attention:
-                qf = Q.fake_quant(q, quant.attn_act_bits)
-                kf = Q.fake_quant(k, quant.attn_act_bits)
-            scores = _scores_float(qf, _gqa_expand(kf, h) if expand else kf, sdt)
-        t_k = k.shape[1]  # == s for self-attn; encoder length for cross
-        mask = _mask(s, t_k, 0, causal, window)
-        scores = scores.astype(sdt) / jnp.sqrt(sdt(dh)) + mask[None, None].astype(sdt)
-        probs = jax.nn.softmax(scores, axis=-1)
-        if use_int:
-            v_s = _gqa_expand(v_m, h) if expand else v_m
-            ctx = _pv_int(probs.astype(jnp.float32), v_s, v_sc, v_off)
-        else:
-            if mode == "train" and quant.enabled and quant.quantize_attention:
-                probs = Q.fake_quant(probs, quant.attn_act_bits)
-            ctx = _pv_float(probs, _gqa_expand(v, h) if expand else v, x.dtype)
-        if cache is not None and kv_override is None:
-            if not quantized:
-                k_m = k.astype(cache["k"].dtype)
-                v_m = v.astype(cache["v"].dtype)
-                k_sc = v_sc = k_off = v_off = None
-            elif not use_int:
+        # k/v take their cache representation under attn.qk: the int scores read it
+        with jax.named_scope("attn.qk"):
+            if use_int and use_binary:
+                kq = _binarize_rows(k)
+                # cache affines are per-row (B,) — drop the keepdims axes
+                k_sc = jnp.reshape(kq.scale, (b,))
+                k_off = jnp.reshape(kq.offset, (b,))
+                k_m = packing.pack_bits(kq.mantissa.astype(jnp.uint32), 1, axis=-1)
+                v_sc, v_off = _calibrate_rows(v)
+                v_m = _quantize_to_cache(v, v_sc, v_off)
+                scores = _scores_binary(
+                    q, k_m.transpose(0, 2, 1, 3), k_sc, k_off, dh, "attn.qk", qk_backend
+                )
+            elif use_int:
                 k_sc, k_off = _calibrate_rows(k)
                 v_sc, v_off = _calibrate_rows(v)
                 k_m = _quantize_to_cache(k, k_sc, k_off)
                 v_m = _quantize_to_cache(v, v_sc, v_off)
-            new_cache = _write_prefill_cache(
-                cache, k_m, v_m, s, cache_len, windowed,
-                k_sc, k_off, v_sc, v_off,
-            )
+                k_s = _gqa_expand(k_m, h) if expand else k_m
+                scores = _scores_int(q, k_s, k_sc, k_off, quant.attn_act_bits, qk_backend)
+            else:
+                qf = q
+                kf = k
+                if mode == "train" and quant.enabled and quant.quantize_attention:
+                    qf = Q.fake_quant(q, quant.attn_act_bits)
+                    kf = Q.fake_quant(k, quant.attn_act_bits)
+                scores = _scores_float(qf, _gqa_expand(kf, h) if expand else kf, sdt)
+            t_k = k.shape[1]  # == s for self-attn; encoder length for cross
+            mask = _mask(s, t_k, 0, causal, window)
+            scores = scores.astype(sdt) / jnp.sqrt(sdt(dh)) + mask[None, None].astype(sdt)
+        with jax.named_scope("attn.av"):
+            probs = jax.nn.softmax(scores, axis=-1)
+            if use_int:
+                v_s = _gqa_expand(v_m, h) if expand else v_m
+                ctx = _pv_int(probs.astype(jnp.float32), v_s, v_sc, v_off)
+            else:
+                if mode == "train" and quant.enabled and quant.quantize_attention:
+                    probs = Q.fake_quant(probs, quant.attn_act_bits)
+                ctx = _pv_float(probs, _gqa_expand(v, h) if expand else v, x.dtype)
+        with jax.named_scope("attn.cache"):
+            if cache is not None and kv_override is None:
+                if not quantized:
+                    k_m = k.astype(cache["k"].dtype)
+                    v_m = v.astype(cache["v"].dtype)
+                    k_sc = v_sc = k_off = v_off = None
+                elif not use_int:
+                    k_sc, k_off = _calibrate_rows(k)
+                    v_sc, v_off = _calibrate_rows(v)
+                    k_m = _quantize_to_cache(k, k_sc, k_off)
+                    v_m = _quantize_to_cache(v, v_sc, v_off)
+                new_cache = _write_prefill_cache(
+                    cache, k_m, v_m, s, cache_len, windowed,
+                    k_sc, k_off, v_sc, v_off,
+                )
     else:
         # ---- single-token decode over the cache --------------------------
         # ``pos`` is per-row: every slot advances its own cursor, so a packed
         # continuous-batching batch mixes requests at unrelated positions.
-        pos = jnp.broadcast_to(jnp.reshape(cache["pos"], (-1,)), (b,))  # (B,)
-        slot = pos % cache_len if windowed else pos
-        if quantized:
-            k_sc, k_off = cache["k_scale"], cache["k_offset"]
-            v_sc, v_off = cache["v_scale"], cache["v_offset"]
-            if use_binary:
-                # stream ONE packed row: binarize on the fixed prefill grid
-                k_m = _binarize_to_cache(k, k_sc, k_off)
+        with jax.named_scope("attn.cache"):
+            pos = jnp.broadcast_to(jnp.reshape(cache["pos"], (-1,)), (b,))  # (B,)
+            slot = pos % cache_len if windowed else pos
+            if quantized:
+                k_sc, k_off = cache["k_scale"], cache["k_offset"]
+                v_sc, v_off = cache["v_scale"], cache["v_offset"]
+                if use_binary:
+                    # stream ONE packed row: binarize on the fixed prefill grid
+                    k_m = _binarize_to_cache(k, k_sc, k_off)
+                else:
+                    k_m = _quantize_to_cache(k, k_sc, k_off)
+                v_m = _quantize_to_cache(v, v_sc, v_off)
             else:
-                k_m = _quantize_to_cache(k, k_sc, k_off)
-            v_m = _quantize_to_cache(v, v_sc, v_off)
-        else:
-            k_m = k.astype(cache["k"].dtype)
-            v_m = v.astype(cache["v"].dtype)
-        row_write = jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(c, u, i, 0)
-        )
-        new_k = row_write(cache["k"], k_m, slot)
-        new_v = row_write(cache["v"], v_m, slot)
-        new_cache = dict(cache, k=new_k, v=new_v, pos=cache["pos"] + 1)
-
-        t = cache_len
-        posc = pos[:, None]  # (B, 1)
-        if windowed:
-            # absolute position held by slot j after writing at `slot`
-            j = jnp.arange(t)[None, :]
-            slot_abs = j + t * ((posc - j) // t)
-            valid = slot_abs >= 0
-            rel_ok = slot_abs > posc - cfg.window_size  # ring holds exactly W
-            valid &= rel_ok & (slot_abs <= posc)
-        else:
-            valid = jnp.arange(t)[None, :] <= posc
-            if window:
-                valid &= jnp.arange(t)[None, :] > posc - window
-        expand = cfg.gqa_mode == "expand"
-        if use_int and use_binary:
-            scores = _scores_binary(
-                q, new_k.transpose(0, 2, 1, 3), k_sc, k_off, dh, "attn.qk", qk_backend
+                k_m = k.astype(cache["k"].dtype)
+                v_m = v.astype(cache["v"].dtype)
+            row_write = jax.vmap(
+                lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(c, u, i, 0)
             )
-        elif use_int:
-            k_s = _gqa_expand(new_k, h) if expand else new_k
-            scores = _scores_int(q, k_s, k_sc, k_off, quant.attn_act_bits, qk_backend)
-        else:
-            src_k = new_k
-            if quantized:
-                src_k = _dequantize_from_cache(src_k, k_sc, k_off, x.dtype)
-            scores = _scores_float(q, _gqa_expand(src_k, h) if expand else src_k)
-        scores = scores / jnp.sqrt(jnp.float32(dh))
-        scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        if use_int:
-            v_s = _gqa_expand(new_v, h) if expand else new_v
-            ctx = _pv_int(probs, v_s, v_sc, v_off)
-        else:
-            src_v = new_v
-            if quantized:
-                src_v = _dequantize_from_cache(src_v, v_sc, v_off, x.dtype)
-            ctx = _pv_float(probs, _gqa_expand(src_v, h) if expand else src_v, x.dtype)
+            new_k = row_write(cache["k"], k_m, slot)
+            new_v = row_write(cache["v"], v_m, slot)
+            new_cache = dict(cache, k=new_k, v=new_v, pos=cache["pos"] + 1)
 
-    out = L.qlinear(
-        p["o"], _merge_heads(ctx).astype(x.dtype), quant, mode, name="attn.o"
-    )
-    return out, new_cache
+        with jax.named_scope("attn.qk"):
+            t = cache_len
+            posc = pos[:, None]  # (B, 1)
+            if windowed:
+                # absolute position held by slot j after writing at `slot`
+                j = jnp.arange(t)[None, :]
+                slot_abs = j + t * ((posc - j) // t)
+                valid = slot_abs >= 0
+                rel_ok = slot_abs > posc - cfg.window_size  # ring holds exactly W
+                valid &= rel_ok & (slot_abs <= posc)
+            else:
+                valid = jnp.arange(t)[None, :] <= posc
+                if window:
+                    valid &= jnp.arange(t)[None, :] > posc - window
+            expand = cfg.gqa_mode == "expand"
+            if use_int and use_binary:
+                scores = _scores_binary(
+                    q, new_k.transpose(0, 2, 1, 3), k_sc, k_off, dh, "attn.qk", qk_backend
+                )
+            elif use_int:
+                k_s = _gqa_expand(new_k, h) if expand else new_k
+                scores = _scores_int(q, k_s, k_sc, k_off, quant.attn_act_bits, qk_backend)
+            else:
+                src_k = new_k
+                if quantized:
+                    src_k = _dequantize_from_cache(src_k, k_sc, k_off, x.dtype)
+                scores = _scores_float(q, _gqa_expand(src_k, h) if expand else src_k)
+            scores = scores / jnp.sqrt(jnp.float32(dh))
+            scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
+        with jax.named_scope("attn.av"):
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            if use_int:
+                v_s = _gqa_expand(new_v, h) if expand else new_v
+                ctx = _pv_int(probs, v_s, v_sc, v_off)
+            else:
+                src_v = new_v
+                if quantized:
+                    src_v = _dequantize_from_cache(src_v, v_sc, v_off, x.dtype)
+                ctx = _pv_float(probs, _gqa_expand(src_v, h) if expand else src_v, x.dtype)
+    return ctx, new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -93,8 +93,17 @@ def qlinear(
 
     ``name`` identifies the layer site (e.g. "ffn.up") for per-layer backend
     overrides (``QuantConfig.backend_overrides``); unnamed sites use the
-    config's default backend.
+    config's default backend.  A named site runs under
+    ``jax.named_scope(name)``, so its device ops (weight unpack, activation
+    quantization, QMM, epilogue) carry the site name in a profiler trace.
     """
+    if not name:
+        return _qlinear(p, x, quant, mode, act_bits, name)
+    with jax.named_scope(name):
+        return _qlinear(p, x, quant, mode, act_bits, name)
+
+
+def _qlinear(p, x, quant: QuantConfig, mode: str, act_bits: Optional[int], name: str):
     if mode == "float" or not quant.enabled:
         w = p["w"] if "w" in p else None
         if w is None:

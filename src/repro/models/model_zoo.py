@@ -390,8 +390,9 @@ def prefill(
             idx = jnp.broadcast_to((rows - 1)[:, None, None], (b, 1, x.shape[-1]))
             x_last = jnp.take_along_axis(x, idx, axis=1)
             new_stack = _set_stack_pos(new_stack, rows)
-        x = L.rmsnorm(params["final_norm"], x_last, cfg.norm_eps)
-        logits = L.unembed(params, x, cfg.tie_embeddings)[:, 0]
+        with jax.named_scope("head"):
+            x = L.rmsnorm(params["final_norm"], x_last, cfg.norm_eps)
+            logits = L.unembed(params, x, cfg.tie_embeddings)[:, 0]
         return logits, dict(cache, stack=new_stack)
 
 
@@ -403,7 +404,9 @@ def decode_step(
 ) -> Tuple[jax.Array, dict]:
     """One decode step. tokens (B,) int32 -> logits (B, V) + updated cache.
 
-    Runs under the "decode" autotune phase (see ``prefill``)."""
+    Runs under the "decode" autotune phase (see ``prefill``).  The final
+    norm and the unembedding run under ``jax.named_scope("head")``, as in
+    ``prefill``."""
     with dispatch.tuning_phase("decode"):
         b = tokens.shape[0]
         pos_rows = jnp.reshape(_cache_pos(cache["stack"], cfg), (-1,))
@@ -417,8 +420,9 @@ def decode_step(
         x, new_stack, _ = T.stack_apply(
             params["stack"], x, cfg, "serve", positions, cache["stack"], encoder_out
         )
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = L.unembed(params, x, cfg.tie_embeddings)[:, 0]
+        with jax.named_scope("head"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = L.unembed(params, x, cfg.tie_embeddings)[:, 0]
         return logits, dict(cache, stack=new_stack)
 
 

@@ -37,6 +37,7 @@ persist/restore the measured verdicts across serving processes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -261,10 +262,28 @@ class ServeEngine:
       serving and tries again at the next boundary.
 
     The rids of the requests the last ``run``/``resume`` ended "failed" are
-    kept on ``last_failed``; its event trace on ``last_events`` (kinds:
-    admit/prefill/insert/decode_tick/finish/reset plus step_fault/
-    retry_tick/backend_fault/demote/nan_logits/requeue/request_failed/
-    prefill_fault/deadline_miss/snapshot/snapshot_failed/resume).
+    kept on ``last_failed``; its event trace on ``last_events``.  Every
+    entry has ``kind`` and ``t`` (seconds on the engine clock, from the start
+    of ``run``).  Spans (:meth:`_span`) also have ``end`` and ``parent``
+    (the index of the enclosing span, or None) and open a
+    ``serve.<kind>`` annotation on the profiler's host plane:
+
+    * ``admit`` (rid, slot, prompt_len), admission to the first token's
+      emit, with children ``prefill`` (the call to ``Z.prefill``: retrace,
+      compile-cache load, eager dispatch), ``insert`` (``cache_insert``),
+      ``fetch`` (waiting for the logits and their transfer) and ``sample``
+      (the first token's sample and emit);
+    * ``tick`` (tick), one decode tick, a profiler step, with children
+      ``decode`` (dispatch of the jitted step), ``fetch`` (waiting for the
+      logits, their transfer, the fault injector's hook), ``sample`` (every
+      row's sample, emit and ``on_token``), and per finished slot
+      ``finish`` or ``deadline_miss`` (rid, slot: its ``cache_reset``).
+
+    Point events: decode_tick (rids; stamped after the fetch, at the same
+    instant as that tick's tokens), reset, step_fault, retry_tick,
+    backend_fault, demote, nan_logits, requeue, request_failed,
+    prefill_fault, deadline_miss of a queued request, snapshot,
+    snapshot_failed, resume.
     """
 
     def __init__(
@@ -314,6 +333,7 @@ class ServeEngine:
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
         self.mesh = mesh
         self.last_events: List[Dict] = []
+        self._open: List[int] = []  # indices of the spans now open, innermost last
         # rids of the requests the last run/resume terminally failed
         self.last_failed: List[int] = []
         self.autotune_cache_path = autotune_cache_path
@@ -346,17 +366,37 @@ class ServeEngine:
     def _event(self, kind: str, t: float, **kw) -> None:
         self.last_events.append(dict(kind=kind, t=t, **kw))
 
-    def _admit(self, req: Request, slot: int, cache: dict, now: float):
-        """Exact-length batch-1 prefill + splice into ``slot``."""
-        req.t_admitted = now
-        self._event("admit", now, rid=req.rid, slot=slot, prompt_len=len(req.prompt))
+    @contextlib.contextmanager
+    def _span(self, kind: str, **fields):
+        """Record the block as a span in ``last_events`` and annotate it as
+        ``serve.<kind>`` on the profiler's host plane (a profiler step for
+        ``tick``), on the device trace's clock.  Yields the entry."""
+        parent = self._open[-1] if self._open else None
+        entry = dict(kind=kind, t=self._clock(), parent=parent, **fields)
+        self._open.append(len(self.last_events))
+        self.last_events.append(entry)
+        if kind == "tick":
+            note = jax.profiler.StepTraceAnnotation("serve.tick", step_num=fields["tick"])
+        else:
+            note = jax.profiler.TraceAnnotation(f"serve.{kind}", **fields)
+        try:
+            with note:
+                yield entry
+        finally:
+            entry["end"] = self._clock()
+            self._open.pop()
+
+    def _admit(self, req: Request, slot: int, cache: dict):
+        """Exact-length batch-1 prefill spliced into ``slot``; returns the
+        host logits and the packed cache."""
         slot_cache = Z.init_slot_cache(self.max_len, self.cfg)
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
-        logits, slot_cache = Z.prefill(self.params, tokens, self.cfg, slot_cache)
-        self._event("prefill", time.perf_counter() - self._t0, rid=req.rid, slot=slot)
-        cache = Z.cache_insert(cache, slot_cache, slot)
-        self._event("insert", time.perf_counter() - self._t0, rid=req.rid, slot=slot)
-        return np.asarray(logits)[0], cache
+        with self._span("prefill", rid=req.rid), jax.named_scope("prefill"):
+            logits, slot_cache = Z.prefill(self.params, tokens, self.cfg, slot_cache)
+        with self._span("insert", rid=req.rid):
+            cache = Z.cache_insert(cache, slot_cache, slot)
+        with self._span("fetch", rid=req.rid):
+            return np.asarray(logits)[0], cache
 
     def _emit(self, req: Request, token: int, now: float) -> None:
         req.output.append(token)
@@ -408,9 +448,9 @@ class ServeEngine:
         slot.req.state = state
         slot.req.t_finished = now
         kind = "finish" if state == STATE_OK else "deadline_miss"
-        self._event(kind, now, rid=slot.req.rid, slot=i)
-        st.cache = Z.cache_reset(st.cache, i, self.cfg, self.max_len)
-        self._event("reset", self._clock(), rid=slot.req.rid, slot=i)
+        with self._span(kind, rid=slot.req.rid, slot=i):
+            st.cache = Z.cache_reset(st.cache, i, self.cfg, self.max_len)
+            self._event("reset", self._clock(), rid=slot.req.rid, slot=i)
         st.slots[i] = None
 
     def _note_backend_failure(self, backend: str, now: float) -> None:
@@ -475,43 +515,51 @@ class ServeEngine:
     def _serve(self, st: _EngineState) -> None:
         """Drive ``st`` to completion (shared by :meth:`run` and
         :meth:`resume`); every fault-policy decision lives here."""
-        from repro.runtime.faults import BackendFault, FaultInjector
+        from repro.runtime.faults import FaultInjector
 
         inj = FaultInjector(self.fault_plan)
         clock = self._clock
 
         while st.queue or any(s is not None for s in st.slots):
             # ---- deadline sweep over the waiting queue -------------------
+            # (kept by identity: Request's dataclass __eq__ compares prompts)
             now = clock()
-            for req in [r for r in st.queue if self._expired(r, now)]:
-                st.queue.remove(req)
+            waiting = []
+            for req in st.queue:
+                if not self._expired(req, now):
+                    waiting.append(req)
+                    continue
                 req.state = STATE_DEADLINE
                 req.t_finished = now
                 self._event("deadline_miss", now, rid=req.rid, slot=None)
+            st.queue = waiting
 
             # ---- admission: fill free slots from arrived requests --------
             while st.queue and st.queue[0].arrival_s <= clock() and None in st.slots:
                 req = st.queue.pop(0)
                 i = st.slots.index(None)
-                try:
-                    inj.before_prefill(req.rid)
-                    logits, st.cache = self._admit(req, i, st.cache, clock())
-                except Exception as e:  # noqa: BLE001 — contained per-request
-                    self._event(
-                        "prefill_fault", clock(), rid=req.rid, error=repr(e)
-                    )
-                    self._requeue(st, req, slot=None)
-                    continue
-                if not np.all(np.isfinite(logits)):
-                    self._event("nan_logits", clock(), rid=req.rid, slot=i)
-                    self._requeue(st, req, slot=None)
-                    continue
-                slot = _Slot(req, req.max_new_tokens, _request_rng(self.seed, req.rid))
-                tok = _sample(logits, req.temperature, slot.rng)
-                self._emit(req, tok, clock())
-                slot.remaining -= 1
-                st.slots[i] = slot
-                st.cur[i] = tok
+                with self._span("admit", rid=req.rid, slot=i, prompt_len=len(req.prompt)) as span:
+                    req.t_admitted = span["t"]
+                    try:
+                        inj.before_prefill(req.rid)
+                        logits, st.cache = self._admit(req, i, st.cache)
+                    except Exception as e:  # noqa: BLE001 — contained per-request
+                        self._event(
+                            "prefill_fault", clock(), rid=req.rid, error=repr(e)
+                        )
+                        self._requeue(st, req, slot=None)
+                        continue
+                    if not np.all(np.isfinite(logits)):
+                        self._event("nan_logits", clock(), rid=req.rid, slot=i)
+                        self._requeue(st, req, slot=None)
+                        continue
+                    with self._span("sample", rid=req.rid):
+                        slot = _Slot(req, req.max_new_tokens, _request_rng(self.seed, req.rid))
+                        tok = _sample(logits, req.temperature, slot.rng)
+                        self._emit(req, tok, clock())
+                    slot.remaining -= 1
+                    st.slots[i] = slot
+                    st.cur[i] = tok
                 if slot.remaining == 0:
                     self._finish(st, i, clock())
             if all(s is None for s in st.slots):
@@ -519,59 +567,75 @@ class ServeEngine:
                     time.sleep(max(0.0, st.queue[0].arrival_s - clock()))
                 continue
 
-            # ---- one packed decode tick over every slot ------------------
-            # Retried in place on failure: the jitted step does not donate
-            # its cache, so a retry sees identical inputs -> identical
-            # logits.  A BackendFault resets the attempt budget after a
-            # demotion (the engine changed configuration; the next attempt
-            # is a different program).
-            logits = None
-            attempt = 0
-            while True:
-                try:
-                    inj.before_decode(st.tick, demoted=self._demoted)
+            with self._span("tick", tick=st.tick):
+                self._tick(st, inj)
+
+        self.last_failed = [r.rid for r in st.requests if r.state == STATE_FAILED]
+        if self.autotune_cache_path:
+            dispatch.get_cache().save(self.autotune_cache_path)
+
+    def _tick(self, st: _EngineState, inj) -> None:
+        """One packed decode tick over every slot, then the running slots'
+        deadline sweep and the periodic snapshot."""
+        from repro.runtime.faults import BackendFault
+
+        clock = self._clock
+        # Retried in place on failure: the jitted step does not donate
+        # its cache, so a retry sees identical inputs -> identical
+        # logits.  A BackendFault resets the attempt budget after a
+        # demotion (the engine changed configuration; the next attempt
+        # is a different program).
+        logits = None
+        attempt = 0
+        while True:
+            try:
+                inj.before_decode(st.tick, demoted=self._demoted)
+                with self._span("decode"):
                     out, new_cache = self._decode_fn(
                         self.params, jnp.asarray(st.cur), st.cache
                     )
+                with self._span("fetch"):
                     logits = inj.corrupt_logits(st.tick, np.asarray(out))
-                    break
-                except BackendFault as e:
-                    demoted_before = dict(self._demoted)
-                    self._note_backend_failure(e.backend, clock())
-                    if self._demoted != demoted_before:
-                        attempt = 0
-                        continue
-                    attempt += 1
-                except Exception as e:  # noqa: BLE001 — step faults retried
-                    self._event(
-                        "step_fault", clock(), tick=st.tick, error=repr(e)
-                    )
-                    attempt += 1
-                if attempt > self.max_retries:
-                    break
-                backoff = self.retry_backoff_s * (2 ** (attempt - 1))
+                break
+            except BackendFault as e:
+                demoted_before = dict(self._demoted)
+                self._note_backend_failure(e.backend, clock())
+                if self._demoted != demoted_before:
+                    attempt = 0
+                    continue
+                attempt += 1
+            except Exception as e:  # noqa: BLE001 — step faults retried
                 self._event(
-                    "retry_tick", clock(), tick=st.tick, attempt=attempt,
-                    backoff_s=backoff,
+                    "step_fault", clock(), tick=st.tick, error=repr(e)
                 )
-                if backoff > 0:
-                    time.sleep(backoff)
-            if logits is None:
-                # tick retry budget exhausted: the batch is lost, the
-                # requests are not — each replays from its prompt (or fails
-                # terminally once ITS budget is gone).  The engine survives.
-                for i in range(self.slots):
-                    if st.slots[i] is not None:
-                        self._requeue(st, st.slots[i].req, slot=i)
-                continue
-            st.cache = new_cache
-            st.tick += 1
-            now = clock()
+                attempt += 1
+            if attempt > self.max_retries:
+                break
+            backoff = self.retry_backoff_s * (2 ** (attempt - 1))
             self._event(
-                "decode_tick",
-                now,
-                rids=[s.req.rid if s else None for s in st.slots],
+                "retry_tick", clock(), tick=st.tick, attempt=attempt,
+                backoff_s=backoff,
             )
+            if backoff > 0:
+                time.sleep(backoff)
+        if logits is None:
+            # tick retry budget exhausted: the batch is lost, the
+            # requests are not — each replays from its prompt (or fails
+            # terminally once ITS budget is gone).  The engine survives.
+            for i in range(self.slots):
+                if st.slots[i] is not None:
+                    self._requeue(st, st.slots[i].req, slot=i)
+            return
+        st.cache = new_cache
+        st.tick += 1
+        now = clock()
+        self._event(
+            "decode_tick",
+            now,
+            rids=[s.req.rid if s else None for s in st.slots],
+        )
+        done = []
+        with self._span("sample"):
             for i, slot in enumerate(st.slots):
                 if slot is None:
                     continue
@@ -586,30 +650,28 @@ class ServeEngine:
                 slot.remaining -= 1
                 st.cur[i] = tok
                 if slot.remaining == 0:
-                    self._finish(st, i, clock())
+                    done.append(i)
+        for i in done:
+            self._finish(st, i, clock())
 
-            # ---- deadline sweep over running slots -----------------------
-            now = clock()
-            for i in range(self.slots):
-                if st.slots[i] is not None and self._expired(st.slots[i].req, now):
-                    self._finish(st, i, now, state=STATE_DEADLINE)
+        # ---- deadline sweep over running slots -----------------------
+        now = clock()
+        for i in range(self.slots):
+            if st.slots[i] is not None and self._expired(st.slots[i].req, now):
+                self._finish(st, i, now, state=STATE_DEADLINE)
 
-            # ---- periodic crash-recovery snapshot ------------------------
-            if self.snapshot_every and st.tick % self.snapshot_every == 0:
-                try:
-                    inj.on_snapshot(st.snaps)
-                    self._snapshot(st)
-                    self._event("snapshot", clock(), tick=st.tick, ordinal=st.snaps)
-                except Exception as e:  # noqa: BLE001 — snapshots are best-effort
-                    self._event(
-                        "snapshot_failed", clock(), tick=st.tick,
-                        ordinal=st.snaps, error=repr(e),
-                    )
-                st.snaps += 1
-
-        self.last_failed = [r.rid for r in st.requests if r.state == STATE_FAILED]
-        if self.autotune_cache_path:
-            dispatch.get_cache().save(self.autotune_cache_path)
+        # ---- periodic crash-recovery snapshot ------------------------
+        if self.snapshot_every and st.tick % self.snapshot_every == 0:
+            try:
+                inj.on_snapshot(st.snaps)
+                self._snapshot(st)
+                self._event("snapshot", clock(), tick=st.tick, ordinal=st.snaps)
+            except Exception as e:  # noqa: BLE001 — snapshots are best-effort
+                self._event(
+                    "snapshot_failed", clock(), tick=st.tick,
+                    ordinal=st.snaps, error=repr(e),
+                )
+            st.snaps += 1
 
     # -- crash-recoverable engine state -------------------------------------
 

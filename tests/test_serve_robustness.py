@@ -202,6 +202,27 @@ def test_queued_request_past_deadline_is_expired_not_served(model):
     assert [e["rid"] for e in misses] == [done[1].rid]
 
 
+def test_queued_request_past_deadline_behind_a_live_request(model):
+    """Expiry removes the request itself from the queue: a live request
+    waiting ahead of it (same prompt length, so a comparison by value
+    would compare the prompts) stays queued and is served."""
+    cfg, params = model
+    eng = ServeEngine(cfg, params, batch_slots=1, max_len=MAX_LEN, seed=0)
+    head, live, starved = (
+        Request(
+            prompt=(np.arange(5, dtype=np.int32) + i) % cfg.vocab_size,
+            max_new_tokens=4,
+            deadline_s=deadline,
+        )
+        for i, deadline in enumerate((None, None, 0.01))
+    )
+    done = eng.run([head, live, starved])
+    assert [r.state for r in done] == [STATE_OK, STATE_OK, STATE_DEADLINE]
+    assert len(done[1].output) == 4 and not done[2].output
+    misses = [e for e in eng.last_events if e["kind"] == "deadline_miss"]
+    assert [e["rid"] for e in misses] == [done[2].rid]
+
+
 def test_running_request_past_deadline_frees_its_slot(model):
     cfg, params = model
     # 0.2 s injected latency per tick against a 0.5 s deadline: whatever the
