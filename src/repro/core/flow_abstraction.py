@@ -122,6 +122,7 @@ def qmm_flow(
     w_colsum: Optional[jax.Array] = None,
     out_dtype=jnp.float32,
     recenter: bool = True,
+    packed_int_matmul: Optional[Callable] = None,
 ) -> jax.Array:
     """Affine x affine QMM via the computation-flow abstraction.
 
@@ -143,6 +144,10 @@ def qmm_flow(
         lanes; ``QMMBackend.needs_unsigned_mantissas``) pass ``False``: the
         affine identity holds for either representation, so the epilogue is
         shared verbatim.
+      packed_int_matmul: integer MM over the right operand's packed words,
+        ``f(x_int (M, K), w_words uint32 (K/32, N)) -> int32 (M, N)``; takes
+        the place of ``int_matmul`` for a 1-bit weight packed along K, so the
+        K x N mantissa is never formed here.
 
     Returns:
       The full-precision product, shape ``(..., M, N)``.
@@ -154,10 +159,9 @@ def qmm_flow(
         x = quantization.recenter(x)
         w = quantization.recenter(w)
     x1 = x.unpack().mantissa
-    x2 = w.unpack().mantissa
     k = x1.shape[-1]
-    if x2.shape[-2] != k:
-        raise ValueError(f"reduction mismatch: {x1.shape} @ {x2.shape}")
+    if w.logical_shape[-2] != k:
+        raise ValueError(f"reduction mismatch: {x1.shape} @ {w.logical_shape}")
 
     a1 = jnp.asarray(x.scale, out_dtype)
     g1 = jnp.asarray(x.offset, out_dtype)
@@ -165,7 +169,19 @@ def qmm_flow(
     g2 = jnp.asarray(w.offset, out_dtype)
 
     # --- cubic term: pure integer MM on the engine ---
-    xy = int_matmul(x1, x2, x.bits, w.bits).astype(out_dtype)
+    if packed_int_matmul is not None:
+        packed_k = w.packed and w.mantissa.ndim == 2 and w.packed_axis in (0, -2)
+        if not (w.bits == 1 and packed_k):
+            raise ValueError("packed_int_matmul needs a 1-bit (K/32, N) packed weight")
+        xy = packed_int_matmul(x1, w.mantissa).astype(out_dtype)
+        if w_colsum is None:
+            # colsum of {0,1} bits = set bits per column of packed words
+            w_colsum = _int_sum(jax.lax.population_count(w.mantissa), axis=-2)
+    else:
+        x2 = w.unpack().mantissa
+        xy = int_matmul(x1, x2, x.bits, w.bits).astype(out_dtype)
+        if w_colsum is None:
+            w_colsum = _int_sum(x2, axis=-2)
 
     # --- quadratic/rank-1 corrections (the VPU's job in BETA) ---
     out = xy * (a1 * a2)
@@ -173,8 +189,7 @@ def qmm_flow(
     row = _int_sum(x1, axis=-1)[..., None].astype(out_dtype)
     out = out + (a1 * g2) * row
     # g1*a2 * colsum(X2): (..., 1, N) broadcast over M.
-    col = (w_colsum if w_colsum is not None else _int_sum(x2, axis=-2))
-    col = col[..., None, :].astype(out_dtype)
+    col = w_colsum[..., None, :].astype(out_dtype)
     out = out + (g1 * a2) * col
     # g1*g2*K constant.
     out = out + g1 * g2 * jnp.asarray(k, out_dtype)

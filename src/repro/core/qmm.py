@@ -10,6 +10,10 @@ Backends for the integer MM core:
 * ``"mxu"``      — int8 ``lax.dot_general`` (int32 accum). TPU-native: the
                    systolic array does 8-bit integer MACs at ~2x bf16 rate.
                    Default for model forward passes and the dry-run path.
+                   At decode's few rows on a TPU a packed 1-bit weight goes
+                   to ``kernels.binary_qmm.decode_qmm`` as packed words and
+                   is unpacked in VMEM (``packed_core_engages``); otherwise
+                   XLA unpacks it to a K x N int8 array in HBM first.
 * ``"popcount"`` — AND+popcount over bit-packed uint32 lanes — the faithful
                    analogue of BETA's XNOR-popcount DPU. (With the unified
                    unsigned-mantissa form, +-1 XNOR-popcount becomes {0,1}
@@ -56,6 +60,10 @@ __all__ = ["qmm", "and_popcount_matmul", "popcount_int_matmul"]
 # broadcast intermediate to n_chunk * M * Kw words (VMEM-sized blocks in the
 # Pallas kernel play the same role).
 _POPCOUNT_N_CHUNK = 256
+
+#: Most activation rows whose product with a packed 1-bit weight the ``mxu``
+#: backend runs in the decode kernel (see :func:`packed_core_engages`).
+PACKED_CORE_MAX_ROWS = 64
 
 
 def and_popcount_matmul(a_packed: jax.Array, b_packed: jax.Array) -> jax.Array:
@@ -133,6 +141,19 @@ def qmm(
             raise ValueError(
                 f"operands W{w.bits}A{x.bits} do not match engine mode {mode.name}"
             )
+    backend = _resolve(x, w, backend)
+    spec = backend_registry.get_backend(backend)  # ValueError on unknown name
+    if "qmm" not in spec.families:
+        raise ValueError(
+            f"backend {backend!r} serves families {sorted(spec.families)}, "
+            "not the qmm family; scores-only backends go through "
+            "kernels.ops.binary_attn_scores"
+        )
+    return spec.run(x, w, w_colsum=w_colsum, out_dtype=out_dtype)
+
+
+def _resolve(x: QuantTensor, w: QuantTensor, backend: str) -> str:
+    """The backend ``qmm(x, w, backend=backend)`` runs."""
     from repro.core import dispatch
 
     if backend == "auto":
@@ -144,22 +165,46 @@ def qmm(
         for d in x_l[:-1]:
             m *= int(d)
         rank2 = len(x_l) == 2 and len(w_l) == 2  # pallas needs rank-2
-        backend = dispatch.choose_backend(
+        return dispatch.choose_backend(
             m, int(x_l[-1]), int(w_l[-1]), x.bits, w.bits, rank2=rank2
         )
-    else:
-        # Demotions override explicit names too: a backend the serving
-        # engine has pinned away from must not come back via a config
-        # literal or per-layer override while the pin is active.
-        backend = dispatch.resolve_backend(backend)
-    spec = backend_registry.get_backend(backend)  # ValueError on unknown name
-    if "qmm" not in spec.families:
-        raise ValueError(
-            f"backend {backend!r} serves families {sorted(spec.families)}, "
-            "not the qmm family; scores-only backends go through "
-            "kernels.ops.binary_attn_scores"
-        )
-    return spec.run(x, w, w_colsum=w_colsum, out_dtype=out_dtype)
+    # Demotions override explicit names too: a backend the serving
+    # engine has pinned away from must not come back via a config
+    # literal or per-layer override while the pin is active.
+    return dispatch.resolve_backend(backend)
+
+
+def packed_core_engages(x: QuantTensor, w: QuantTensor) -> bool:
+    """Does the ``mxu`` backend hand ``w``'s packed words to the decode kernel
+    (``kernels.binary_qmm.decode_qmm``, which unpacks them in VMEM) rather
+    than unpack them to a K x N int8 array in HBM first?
+
+    Yes for a packed 1-bit ``(K/32, N)`` weight against at most
+    ``PACKED_CORE_MAX_ROWS`` rows of a rank-2 activation on a TPU: decode's
+    slots.  Prefill's prompts (89 tokens and more in every benchmark cell)
+    keep the XLA path, and off the TPU, where the kernel would only be
+    interpreted, every shape does.
+    """
+    from repro.kernels import ops
+
+    x_l = x.logical_shape
+    return (
+        w.bits == 1
+        and w.packed
+        and w.mantissa.ndim == 2
+        and w.packed_axis in (0, -2)
+        and len(x_l) == 2
+        and x_l[0] <= PACKED_CORE_MAX_ROWS
+        and ops.on_tpu()
+    )
+
+
+def int_core(x: QuantTensor, w: QuantTensor, backend: str) -> str:
+    """The integer core ``qmm(x, w, backend=backend)`` runs, for the site log:
+    ``"packed"`` where the ``mxu`` backend's decode kernel reads the packed
+    weight, ``"unpacked"`` otherwise."""
+    packed = _resolve(x, w, backend) == "mxu" and packed_core_engages(x, w)
+    return "packed" if packed else "unpacked"
 
 
 # ---------------------------------------------------------------------------
@@ -168,11 +213,19 @@ def qmm(
 
 
 def _mxu_traffic(m, k, n, act_bits, weight_bits) -> int:
-    # The MXU path consumes *unpacked* int8 mantissas: packed 1-bit weights
-    # are materialized to K x N int8 before the dot (that unpacked footprint
-    # is exactly what the fused kernel avoids).  XLA fuses the epilogue into
+    # Up to PACKED_CORE_MAX_ROWS rows of a 1-bit weight on the TPU, the
+    # decode kernel reads the packed words once and unpacks them in VMEM.
+    # Otherwise XLA consumes *unpacked* int8 mantissas: a u32 broadcast of
+    # the packed words (4 bytes an element) and the int8 K x N array are
+    # each written and read back before the dot.  The epilogue fuses into
     # the dot's consumer, so the output is written once.
-    return m * k + k * n + 4 * m * n + 8 * (m + n)
+    from repro.kernels import ops
+
+    if weight_bits == 1 and m <= PACKED_CORE_MAX_ROWS and ops.on_tpu():
+        w_bytes = 4 * packing.packed_len(k, 1) * n
+    else:
+        w_bytes = 10 * k * n
+    return m * k + w_bytes + 4 * m * n + 8 * (m + n)
 
 
 def _popcount_traffic(m, k, n, act_bits, weight_bits) -> int:
@@ -214,8 +267,19 @@ def _mxu_scores(q_planes: jax.Array, k_planes: jax.Array, *, dh: int) -> jax.Arr
 )
 def _run_mxu(x: QuantTensor, w: QuantTensor, *, w_colsum=None, out_dtype=jnp.float32):
     return flow_abstraction.qmm_flow(
-        x, w, int_matmul=None, w_colsum=w_colsum, out_dtype=out_dtype
+        x,
+        w,
+        int_matmul=None,
+        packed_int_matmul=_decode_core if packed_core_engages(x, w) else None,
+        w_colsum=w_colsum,
+        out_dtype=out_dtype,
     )
+
+
+def _decode_core(a: jax.Array, w_words: jax.Array) -> jax.Array:
+    from repro.kernels import ops
+
+    return ops.decode_qmm_int(a.astype(jnp.int8), w_words)
 
 
 @backend_registry.register_backend(

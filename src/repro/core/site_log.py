@@ -54,6 +54,9 @@ def record(**fields) -> None:
       cfg_bits: the precision QuantConfig declares for this site class
       mantissa_dtype: str dtype of the quantized mantissa fed to the engine
       backend: resolved backend string (qlinear sites only)
+      int_core: "packed" where the ``mxu`` backend's decode kernel read the
+        packed weight and unpacked it in VMEM, else "unpacked" (qlinear
+        sites only; ``qmm.int_core``)
     """
     log = _LOG.get()
     if log is not None:

@@ -19,6 +19,15 @@ Blocking (BlockSpec):
 VMEM @ defaults (bm=bn=128, bk=512): A 64 KiB + Wp 8 KiB + unpacked W 64 KiB
 + acc 64 KiB ~= 200 KiB — comfortably within a v5e core's ~16 MiB VMEM and
 MXU-aligned (every matmul dim a multiple of 128).
+
+``decode_qmm`` is the sibling for decode's few rows, which the ``mxu``
+backend runs: ``bm`` is M rounded up to 32, one block holds the whole packed
+K (to 512 words) and up to 1 MiB of words across N (``decode_block``), so
+granite-8b's ``ffn.gate`` takes 14 grid steps where ``DEFAULT_BLOCK`` takes
+896, and the words are unpacked a byte plane at a time.  VMEM at the largest
+block (bm 64, bkw 512, bn 512): words 1 MiB and activations 1 MiB, each
+double-buffered, 1 MiB of shifted words, a 128 KiB accumulator and two
+128 KiB output blocks ~= 5.4 MiB, within the 16 MiB scoped by default.
 """
 
 from __future__ import annotations
@@ -28,8 +37,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["binary_qmm", "DEFAULT_BLOCK"]
+__all__ = ["binary_qmm", "DEFAULT_BLOCK", "decode_qmm", "decode_block"]
 
 DEFAULT_BLOCK = (128, 128, 512)  # bm, bn, bk
 _LANES_PER_WORD = 32
@@ -103,3 +113,104 @@ def binary_qmm(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
     )(a, w_packed)
+
+
+# ---------------------------------------------------------------------------
+# Decode: a few rows against the whole packed weight
+# ---------------------------------------------------------------------------
+
+#: Words of K in one block; longer K is cut into lane-aligned slabs of this.
+_DECODE_MAX_BKW = 512
+#: Bytes of packed weight in one block (double-buffered by the pipeline).
+_DECODE_W_BLOCK_BYTES = 1 << 20
+_DECODE_BN = (1024, 512, 256)
+_BITS_PER_BYTE = 8
+
+
+def decode_block(m: int, kw: int, n: int):
+    """``(bm, bn, bkw)`` for :func:`decode_qmm` at ``(m, kw, n)``; the caller
+    pads M, Kw and N to multiples.
+
+    ``bm`` is M rounded up to the int8 sublane tile (32).  ``bkw`` is the
+    whole packed K up to 512 words (K = 16384), else 512.  ``bn`` is the
+    widest of 1024/512/256 that divides N (rounded up to 128) and keeps a
+    weight block within 1 MiB, else 128.  granite-8b's ``ffn.gate`` (Kw 128,
+    N 14336) gets 14 steps of (128, 1024) words; ``ffn.down`` (Kw 448, N
+    4096) 8 of (448, 512).
+    """
+    bm = -(-m // 32) * 32
+    bkw = min(kw, _DECODE_MAX_BKW)
+    n128 = -(-n // 128) * 128
+    fits = [c for c in _DECODE_BN if n128 % c == 0 and 4 * bkw * c <= _DECODE_W_BLOCK_BYTES]
+    return bm, (fits[0] if fits else 128), bkw
+
+
+def _decode_kernel(a_ref, wp_ref, o_ref):
+    """One (bm, bn) output tile x one bkw-word slice of K.
+
+    Shifting the packed words right by ``b`` and masking with 0x01010101
+    leaves bit ``b`` of each of their four bytes in that byte's low bit;
+    read as int8 (``pltpu.bitcast``, byte ``beta`` of word row ``r`` to row
+    ``4r + beta``), that is a (4*bkw, bn) slab of {0,1} weights whose row
+    ``i`` is ``k = 8i + b``.  Eight slabs cover the slice, each one shift
+    and one mask per word, so every op unpacks four weights; ``a_ref[b]``
+    holds the activation columns ``8i + b`` to meet slab ``b`` on the MXU.
+    No slab outlives its dot, so the u32 temporary is one block of words.
+    """
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def plane(b, acc):
+        bits = (wp_ref[...] >> b.astype(jnp.uint32)) & jnp.uint32(0x01010101)
+        return acc + jax.lax.dot_general(
+            a_ref[b],
+            pltpu.bitcast(bits, jnp.int8),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+
+    o_ref[...] += jax.lax.fori_loop(
+        0, _BITS_PER_BYTE, plane, jnp.zeros(o_ref.shape, jnp.int32), unroll=True
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def decode_qmm(
+    a_planes: jax.Array,
+    w_packed: jax.Array,
+    *,
+    block,
+    interpret: bool = False,
+) -> jax.Array:
+    """Integer MM ``a @ unpack(w_packed)`` for decode's few rows.
+
+    Args:
+      a_planes: int8 ``(8, M, 4*Kw)``, ``a_planes[b, m, i] = a[m, 8i + b]``
+        (``ops.decode_qmm_int`` lays the activation out so).
+      w_packed: uint32 ``(Kw, N)`` bit-packed binary weight mantissas.
+      block: (bm, bn, bkw) from :func:`decode_block`; M, N, Kw multiples.
+      interpret: run the kernel body in Python (CPU validation mode).
+
+    Returns:
+      int32 ``(M, N)``.
+    """
+    _, m, k4 = a_planes.shape
+    kw, n = w_packed.shape
+    bm, bn, bkw = block
+    if k4 != 4 * kw:
+        raise ValueError(f"packed-K mismatch: {a_planes.shape} vs {w_packed.shape}")
+    if m != bm or n % bn or kw % bkw:
+        raise ValueError(f"shapes (M {m}, Kw {kw}, N {n}) do not fit block {block}")
+    return pl.pallas_call(
+        _decode_kernel,
+        grid=(n // bn, kw // bkw),
+        in_specs=[
+            pl.BlockSpec((_BITS_PER_BYTE, bm, 4 * bkw), lambda j, kk: (0, 0, kk)),
+            pl.BlockSpec((bkw, bn), lambda j, kk: (kk, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda j, kk: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        interpret=interpret,
+    )(a_planes, w_packed)

@@ -25,6 +25,7 @@ from repro.kernels import popcount_qmm as _pq
 __all__ = [
     "on_tpu",
     "binary_qmm_int",
+    "decode_qmm_int",
     "popcount_qmm_int",
     "bitserial_qmm_int",
     "qmm_pallas",
@@ -82,6 +83,32 @@ def binary_qmm_int(
     w_p = _pad_to(_pad_to(w_packed, 0, kp // 32), 1, bn)
     out = _bq.binary_qmm(
         a_p, w_p, k=kp, block=block, interpret=_auto_interpret(interpret)
+    )
+    return out[:m, :n]
+
+
+def decode_qmm_int(
+    a: jax.Array,
+    w_packed: jax.Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``a (M, K) int8 @ unpack(w_packed) (K, N)`` for decode's few rows,
+    unpacked in VMEM (``binary_qmm.decode_qmm``).
+
+    ``K`` is ``a.shape[1]``, at most ``32 * w_packed.shape[0]``.  Zero
+    padding is exact, as in :func:`binary_qmm_int`; the registered configs'
+    shapes need none on the weight.
+    """
+    m, k = a.shape
+    kw, n = w_packed.shape
+    bm, bn, bkw = _bq.decode_block(m, kw, n)
+    a_p = _pad_to(_pad_to(a, 0, bm), 1, 32 * kw)
+    # a_planes[b, m, i] = a[m, 8i + b]: column 8i + b meets bit b of byte i.
+    a_planes = _pad_to(a_p.reshape(bm, 4 * kw, 8).transpose(2, 0, 1), 2, 4 * bkw)
+    w_p = _pad_to(_pad_to(w_packed, 0, bkw), 1, bn)
+    out = _bq.decode_qmm(
+        a_planes, w_p, block=(bm, bn, bkw), interpret=_auto_interpret(interpret)
     )
     return out[:m, :n]
 

@@ -145,6 +145,7 @@ def _qlinear(p, x, quant: QuantConfig, mode: str, act_bits: Optional[int], name:
                 cfg_bits=quant.act_bits,
                 mantissa_dtype=str(xq.mantissa.dtype),
                 backend=quant.backend_for(name),
+                int_core=QE.int_core(x2, wq, quant.backend_for(name)),
             )
         out = QE.qmm(
             x2, wq, backend=quant.backend_for(name), w_colsum=p.get("w_colsum")

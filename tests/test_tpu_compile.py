@@ -5,7 +5,8 @@ for a described topology.  These tests compile the main serving path at
 granite-8b's published widths: each Pallas kernel at the granite-8b FFN
 shapes (it must lower to Mosaic — a ``tpu_custom_call`` in the program —
 not merely pass in interpret mode), the one-layer-at-a-time weight setup,
-and one decode step, whose memory must fit the chip's HBM.
+and one decode step, whose memory must fit the chip's HBM and whose QMM
+sites must read the packed weights in the decode kernel.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -14,9 +15,11 @@ entry compiled for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -61,6 +64,11 @@ def _kernel_args(kernel, sharding, m, k, n):
             lambda a, w: ops.binary_qmm_int(a, w, k, interpret=False),
             (s((m, k), jnp.int8), s((kw, n), jnp.uint32)),
         )
+    if kernel == "decode_qmm":  # W1A8 at decode's rows, unpacked in VMEM
+        return (
+            lambda a, w: ops.decode_qmm_int(a, w, interpret=False),
+            (s((m, k), jnp.int8), s((kw, n), jnp.uint32)),
+        )
     if kernel == "popcount_qmm":  # W1A1
         return (
             lambda a, b: ops.popcount_qmm_int(a, b, interpret=False),
@@ -82,7 +90,7 @@ def _kernel_args(kernel, sharding, m, k, n):
 
 @pytest.mark.parametrize("site", sorted(FFN))
 @pytest.mark.parametrize(
-    "kernel", ["binary_qmm", "popcount_qmm", "bitserial_qmm", "fused_qmm"]
+    "kernel", ["binary_qmm", "decode_qmm", "popcount_qmm", "bitserial_qmm", "fused_qmm"]
 )
 def test_kernel_lowers_to_mosaic(one_chip, kernel, site):
     fn, args = _kernel_args(kernel, one_chip, *FFN[site])
@@ -124,3 +132,36 @@ def test_granite_decode_step_fits_one_chip(one_chip):
         .compile()
     )
     assert _bytes(compiled) < HBM_BYTES
+
+
+def test_granite_decode_step_unpacks_weights_in_vmem(one_chip, monkeypatch):
+    """At 8 slots every QMM site runs the decode kernel on the packed words:
+    no u32 broadcast or int8 copy of a site's K x N weight reaches HBM (the
+    XLA unpack path's temporaries are 539 MB, its ffn u32 broadcast alone
+    235 MB)."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # lowered for the v5e
+    cfg = get_config("granite-8b")
+    shapes = jax.eval_shape(
+        lambda k: Z.init_serving_params(k, cfg), jax.random.PRNGKey(0)
+    )
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(one_chip, a.shape, a.dtype), t
+    )
+    cache = place(jax.eval_shape(lambda: Z.init_cache(8, 4096, cfg)))
+    compiled = (
+        jax.jit(lambda p, t, c: Z.decode_step(p, t, cfg, c))
+        .lower(place(shapes), _sds(one_chip, (8,), jnp.int32), cache)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 7  # q k v o gate up down
+    d, kv, f = cfg.d_model, cfg.n_kv_heads * cfg.d_head, cfg.d_ff
+    smallest_site = min(d * kv, d * f)  # attn.k/v
+    stacked = {
+        tuple(a.shape) for a in jax.tree.leaves(shapes) if a.dtype == jnp.uint32
+    }
+    for dims in re.findall(r"u32\[([0-9,]+)\]", text):
+        shape = tuple(int(n) for n in dims.split(","))
+        if shape not in stacked:  # the packed weights themselves
+            assert np.prod(shape) < smallest_site, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 235e6
