@@ -28,6 +28,7 @@ from typing import Dict, Optional, Tuple
 __all__ = [
     "QuantConfig",
     "MoEConfig",
+    "RopeScaling",
     "SSMConfig",
     "EncoderConfig",
     "ArchConfig",
@@ -154,6 +155,28 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN frequency scaling of RoPE (arXiv:2309.00071), with DeepSeek's
+    attention-temperature terms, as a model's ``config.json`` publishes it
+    under ``rope_scaling`` (``type: yarn``).
+
+    Frequencies whose wavelength fits ``beta_fast`` times in the original
+    context keep their value, those that fit fewer than ``beta_slow`` times
+    are divided by ``factor``, and a linear ramp joins the two
+    (``layers.rope_inv_freq``).  The softmax scale is multiplied by
+    ``yarn_mscale(factor, mscale_all_dim) ** 2``, cos and sin by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
+    """
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class EncoderConfig:
     """Frontend/encoder for enc-dec (whisper) and VLM (internvl2) archs.
 
@@ -186,6 +209,7 @@ class ArchConfig:
     qk_norm: bool = False
     ffn_type: str = "silu_glu"  # "gelu" | "silu_glu" | "gelu_glu"
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None  # YaRN; MLA attention only
     local_rope_theta: float = 0.0  # gemma3 uses a different theta locally
     pos_embedding: str = "rope"  # "rope" | "learned" | "sinusoidal" | "none"
     causal: bool = True
@@ -210,6 +234,10 @@ class ArchConfig:
     source: str = ""  # provenance note: [source; verified-tier]
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):  # as a configuration file states it
+            object.__setattr__(self, "rope_scaling", RopeScaling(**self.rope_scaling))
+        if self.rope_scaling is not None and self.mla is None:
+            raise ValueError(f"{self.name}: rope_scaling is implemented for MLA attention only")
         if self.d_head == 0:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
         n_pattern = self.n_layers - len(self.prefix_layers)

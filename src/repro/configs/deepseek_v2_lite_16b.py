@@ -3,13 +3,15 @@
 [arXiv:2405.04434; hf]  27L d_model=2048 16H d_ff(expert)=1408
 vocab=102400.  First layer dense (d_ff 10944), remaining 26 MoE.
 MLA: kv_lora 512, q projected directly (no q LoRA), qk_nope 128,
-qk_rope 64, v_head 128.  Softmax router, top-6.
+qk_rope 64, v_head 128.  Softmax router, top-6, weights not renormalised.
+RoPE: YaRN, factor 40 over an original 4096 positions, beta_fast 32,
+beta_slow 1, mscale = mscale_all_dim = 0.707.
 (The assignment banner lists both "64e top-6" and "160 routed"; we follow
 the HF deepseek-v2-lite config: 64 routed experts, 2 shared, top-6 —
 the 160-routed figure belongs to full deepseek-v2.)
 """
 
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, QuantConfig, register
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, QuantConfig, RopeScaling, register
 
 CONFIG = register(
     ArchConfig(
@@ -26,6 +28,14 @@ CONFIG = register(
         pattern_period=("Mm",),
         ffn_type="silu_glu",
         rope_theta=10000.0,
+        rope_scaling=RopeScaling(
+            factor=40.0,
+            original_max_position_embeddings=4096,
+            beta_fast=32.0,
+            beta_slow=1.0,
+            mscale=0.707,
+            mscale_all_dim=0.707,
+        ),
         mla=MLAConfig(
             kv_lora_rank=512,
             q_lora_rank=0,

@@ -3,11 +3,13 @@
 [arXiv:2412.19437; hf]  61L d_model=7168 128H d_ff(expert)=2048
 vocab=129280.  First 3 layers dense (d_ff 18432), remaining 58 MoE.
 MLA: kv_lora 512, q_lora 1536, qk_nope 128, qk_rope 64, v_head 128.
+RoPE: YaRN, factor 40 over an original 4096 positions, beta_fast 32,
+beta_slow 1, mscale = mscale_all_dim = 1.
 Router: sigmoid scoring with top-8 of 256 routed + 1 shared expert.
 MTP: one extra multi-token-prediction head (depth 1), training-loss only.
 """
 
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, QuantConfig, register
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, QuantConfig, RopeScaling, register
 
 CONFIG = register(
     ArchConfig(
@@ -24,6 +26,14 @@ CONFIG = register(
         pattern_period=("Mm",),
         ffn_type="silu_glu",
         rope_theta=10000.0,
+        rope_scaling=RopeScaling(
+            factor=40.0,
+            original_max_position_embeddings=4096,
+            beta_fast=32.0,
+            beta_slow=1.0,
+            mscale=1.0,
+            mscale_all_dim=1.0,
+        ),
         mla=MLAConfig(
             kv_lora_rank=512,
             q_lora_rank=1536,

@@ -131,12 +131,14 @@ def qmm_flow(
         scalar or broadcastable to ``(..., M, 1)`` (per-token).
       w: right operand, logical shape ``(K, N)`` (act x weight) or
         ``(..., K, N)`` (act x act). ``scale``/``offset`` scalar or
-        broadcastable to ``(1, N)`` (per-out-channel).
+        broadcastable to ``(1, N)`` (per-out-channel), or ``(..., M, N)``
+        where each row of ``x`` meets a weight of its own (routed experts).
       int_matmul: integer MM backend ``f(x_int, w_int, x_bits, w_bits)``.
       w_colsum: optional precomputed ``colsum`` of the right mantissa *as the
         integer core consumes it* — re-centered when ``recenter=True``
         (``weight_corrections``), raw otherwise.  For 1-bit weights the two
-        coincide (re-centering is a no-op at bits <= 1).
+        coincide (re-centering is a no-op at bits <= 1).  ``(..., N)``, or
+        ``(..., M, N)`` with a weight per row (then required).
       out_dtype: accumulation dtype of the full-precision epilogue.
       recenter: shift multi-bit mantissas to the signed range before the
         integer MM (exact — absorbed into the offsets).  Backends whose
@@ -145,9 +147,10 @@ def qmm_flow(
         affine identity holds for either representation, so the epilogue is
         shared verbatim.
       packed_int_matmul: integer MM over the right operand's packed words,
-        ``f(x_int (M, K), w_words uint32 (K/32, N)) -> int32 (M, N)``; takes
-        the place of ``int_matmul`` for a 1-bit weight packed along K, so the
-        K x N mantissa is never formed here.
+        ``f(x_int (M, K), w_words uint32 (..., K/32, N)) -> int32 (M, N)``;
+        takes the place of ``int_matmul`` for a 1-bit weight packed along K,
+        so the K x N mantissa is never formed here.  Words with leading axes
+        (a stack of experts) are the function's to pick from.
 
     Returns:
       The full-precision product, shape ``(..., M, N)``.
@@ -170,9 +173,10 @@ def qmm_flow(
 
     # --- cubic term: pure integer MM on the engine ---
     if packed_int_matmul is not None:
-        packed_k = w.packed and w.mantissa.ndim == 2 and w.packed_axis in (0, -2)
+        nd = w.mantissa.ndim
+        packed_k = w.packed and nd >= 2 and w.packed_axis % nd == nd - 2
         if not (w.bits == 1 and packed_k):
-            raise ValueError("packed_int_matmul needs a 1-bit (K/32, N) packed weight")
+            raise ValueError("packed_int_matmul needs a 1-bit (..., K/32, N) packed weight")
         xy = packed_int_matmul(x1, w.mantissa).astype(out_dtype)
         if w_colsum is None:
             # colsum of {0,1} bits = set bits per column of packed words
@@ -188,8 +192,9 @@ def qmm_flow(
     # a1*g2 * rowsum(X1): (..., M, 1) broadcast over N.
     row = _int_sum(x1, axis=-1)[..., None].astype(out_dtype)
     out = out + (a1 * g2) * row
-    # g1*a2 * colsum(X2): (..., 1, N) broadcast over M.
-    col = w_colsum[..., None, :].astype(out_dtype)
+    # g1*a2 * colsum(X2): (..., 1, N) broadcast over M, or one per row.
+    per_row = w_colsum.ndim == xy.ndim
+    col = (w_colsum if per_row else w_colsum[..., None, :]).astype(out_dtype)
     out = out + (g1 * a2) * col
     # g1*g2*K constant.
     out = out + g1 * g2 * jnp.asarray(k, out_dtype)

@@ -28,6 +28,8 @@ granite-8b's ``ffn.gate`` takes 14 grid steps where ``DEFAULT_BLOCK`` takes
 block (bm 64, bkw 512, bn 512): words 1 MiB and activations 1 MiB, each
 double-buffered, 1 MiB of shifted words, a 128 KiB accumulator and two
 128 KiB output blocks ~= 5.4 MiB, within the 16 MiB scoped by default.
+
+``expert_decode_qmm`` is its grouped sibling for routed experts (below).
 """
 
 from __future__ import annotations
@@ -39,7 +41,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["binary_qmm", "DEFAULT_BLOCK", "decode_qmm", "decode_block"]
+__all__ = [
+    "binary_qmm",
+    "DEFAULT_BLOCK",
+    "decode_qmm",
+    "decode_block",
+    "expert_decode_qmm",
+    "expert_block",
+    "EXPERT_TILE_ROWS",
+]
 
 DEFAULT_BLOCK = (128, 128, 512)  # bm, bn, bk
 _LANES_PER_WORD = 32
@@ -214,3 +224,117 @@ def decode_qmm(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
     )(a_planes, w_packed)
+
+
+# ---------------------------------------------------------------------------
+# Routed experts: tiles of rows, each against its expert's packed words
+# ---------------------------------------------------------------------------
+#
+# ``expert_decode_qmm`` is the grouped sibling of ``decode_qmm`` for routed
+# experts: rows come in tiles of ``EXPERT_TILE_ROWS``, each tile routed to
+# one expert of a stacked ``(E, Kw, N)`` packed weight (every layer's
+# experts at once, ``(L * E, Kw, N)``, where a scan over layers reads its
+# layer's in place: ``ops.expert_decode_qmm_int``).  The tiles' expert ids
+# and the number of tiles in use arrive as scalar prefetch, so each grid
+# step's ``index_map`` fetches only its tile's expert; consecutive tiles of
+# one expert, and the unused tail, keep the block already in VMEM.
+
+#: Rows of one tile of :func:`expert_decode_qmm`.  Decode routes a token's few
+#: rows to each of its experts, so a tile of 8 wastes the least padding.
+EXPERT_TILE_ROWS = 8
+
+
+def expert_block(kw: int, n: int):
+    """``(bn, bkw)`` for :func:`expert_decode_qmm` over one expert's
+    ``(Kw, N)`` words; the caller pads N and Kw to multiples.
+
+    ``bkw`` as in :func:`decode_block`.  ``bn`` is the whole N (rounded up
+    to 128) where a block of words stays within 1 MiB, so that one DMA
+    brings a whole expert: deepseek-v2-lite's gate and up (Kw 64, N 1408)
+    and down (Kw 48, padded from 44, N 2048) take one step per tile.  Else
+    the widest of 1024/512/256 that divides N and fits, else 128.
+    """
+    bkw = min(kw, _DECODE_MAX_BKW)
+    n128 = -(-n // 128) * 128
+    if 4 * bkw * n128 <= _DECODE_W_BLOCK_BYTES:
+        return n128, bkw
+    fits = [c for c in _DECODE_BN if n128 % c == 0 and 4 * bkw * c <= _DECODE_W_BLOCK_BYTES]
+    return (fits[0] if fits else 128), bkw
+
+
+def _expert_kernel(tile_expert_ref, n_tiles_ref, a_ref, wp_ref, o_ref):
+    """One tile of rows x one (bkw, bn) block of its expert's words, unpacked
+    a byte plane at a time as in ``_decode_kernel``; a tile past ``n_tiles``
+    (unused) is left zero."""
+    del tile_expert_ref  # read by the index maps
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def plane(b, acc):
+        bits = (wp_ref[...] >> b.astype(jnp.uint32)) & jnp.uint32(0x01010101)
+        return acc + jax.lax.dot_general(
+            a_ref[b],
+            pltpu.bitcast(bits, jnp.int8),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+
+    @pl.when(pl.program_id(0) < n_tiles_ref[0])
+    def _mac():
+        o_ref[...] += jax.lax.fori_loop(
+            0, _BITS_PER_BYTE, plane, jnp.zeros(o_ref.shape, jnp.int32), unroll=True
+        )
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def expert_decode_qmm(
+    tile_expert: jax.Array,
+    n_tiles: jax.Array,
+    a_planes: jax.Array,
+    w_packed: jax.Array,
+    *,
+    block,
+    interpret: bool = False,
+) -> jax.Array:
+    """Integer MM of each tile of rows with its expert's unpacked words.
+
+    Args:
+      tile_expert: int32 ``(n_max,)``, the expert of each tile; tiles past
+        ``n_tiles`` repeat the last expert in use (no new fetch).
+      n_tiles: int32 ``(1,)``, the tiles in use.
+      a_planes: int8 ``(8, n_max * bm, 4*Kw)`` in ``decode_qmm``'s layout,
+        ``bm`` = :data:`EXPERT_TILE_ROWS` rows a tile.
+      w_packed: uint32 ``(E, Kw, N)`` bit-packed binary weight mantissas,
+        ``tile_expert`` indexing the leading axis.
+      block: (bn, bkw) from :func:`expert_block`; N and Kw multiples.
+      interpret: run the kernel body in Python (CPU validation mode).
+
+    Returns:
+      int32 ``(n_max * bm, N)``; rows of unused tiles are zero.
+    """
+    _, rows, k4 = a_planes.shape
+    _, kw, n = w_packed.shape
+    bn, bkw = block
+    bm = EXPERT_TILE_ROWS
+    n_max = tile_expert.shape[0]
+    if k4 != 4 * kw or rows != n_max * bm:
+        raise ValueError(f"shapes {a_planes.shape}, {w_packed.shape} do not fit {n_max} tiles")
+    if n % bn or kw % bkw:
+        raise ValueError(f"shapes (Kw {kw}, N {n}) do not fit block {block}")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_max, n // bn, kw // bkw),
+        in_specs=[
+            pl.BlockSpec((_BITS_PER_BYTE, bm, 4 * bkw), lambda t, j, kk, te, nt: (0, t, kk)),
+            pl.BlockSpec((pl.Squeezed(), bkw, bn), lambda t, j, kk, te, nt: (te[t], kk, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda t, j, kk, te, nt: (t, j)),
+    )
+    return pl.pallas_call(
+        _expert_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.int32),
+        interpret=interpret,
+    )(tile_expert, n_tiles, a_planes, w_packed)

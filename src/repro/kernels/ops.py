@@ -9,7 +9,7 @@ switch the model layer uses.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +26,9 @@ __all__ = [
     "on_tpu",
     "binary_qmm_int",
     "decode_qmm_int",
+    "ExpertTiles",
+    "expert_tiles",
+    "expert_decode_qmm_int",
     "popcount_qmm_int",
     "bitserial_qmm_int",
     "qmm_pallas",
@@ -111,6 +114,92 @@ def decode_qmm_int(
         a_planes, w_p, block=(bm, bn, bkw), interpret=_auto_interpret(interpret)
     )
     return out[:m, :n]
+
+
+class ExpertTiles(NamedTuple):
+    """Rows routed to experts, laid out for ``binary_qmm.expert_decode_qmm``.
+
+    ``n_max`` tiles of ``EXPERT_TILE_ROWS`` rows; each expert's rows fill
+    whole tiles of their own, in expert order, and the tiles past
+    ``n_tiles`` are unused.  ``n_max = R // bm + min(E, R)`` bounds the
+    tiles any routing of R rows needs (``sum_e ceil(g_e / bm)``).
+    """
+
+    tile_expert: jax.Array  # int32 (n_max,): expert of each tile
+    n_tiles: jax.Array  # int32 (1,): tiles in use
+    slot: jax.Array  # int32 (R,): padded row of each routed row
+    source: jax.Array  # int32 (n_max * bm,): routed row of each padded row, R if none
+
+    @property
+    def routed_expert(self) -> jax.Array:
+        """Expert of each routed row, (R,)."""
+        return self.tile_expert[self.slot // _bq.EXPERT_TILE_ROWS]
+
+
+def expert_tiles(row_expert: jax.Array, n_experts: int) -> ExpertTiles:
+    """Group R rows by their expert (``row_expert`` (R,) int32) into tiles."""
+    bm = _bq.EXPERT_TILE_ROWS
+    r = row_expert.shape[0]
+    n_max = r // bm + min(n_experts, r)
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[row_expert].add(1)
+    tiles = (sizes + bm - 1) // bm
+    tile_end = jnp.cumsum(tiles)
+    n_tiles = tile_end[-1]
+    t = jnp.arange(n_max, dtype=jnp.int32)
+    tile_expert = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
+    last = jnp.searchsorted(tile_end, n_tiles - 1, side="right").astype(jnp.int32)
+    tile_expert = jnp.where(t < n_tiles, tile_expert, last)
+    # rank of each row within its expert's rows, in row order
+    order = jnp.argsort(row_expert, stable=True)
+    first = jnp.cumsum(sizes) - sizes  # first sorted index of each expert
+    rank = jnp.zeros((r,), jnp.int32).at[order].set(
+        jnp.arange(r, dtype=jnp.int32) - first[row_expert[order]]
+    )
+    slot = (tile_end - tiles)[row_expert] * bm + rank
+    source = jnp.full((n_max * bm,), r, jnp.int32).at[slot].set(jnp.arange(r, dtype=jnp.int32))
+    return ExpertTiles(tile_expert, n_tiles[None].astype(jnp.int32), slot, source)
+
+
+def expert_decode_qmm_int(
+    a: jax.Array,
+    tiles: ExpertTiles,
+    w_packed: jax.Array,
+    layer: Optional[jax.Array] = None,
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``a (R, K) int8``, row ``r`` times ``unpack(w_packed[e])`` for the
+    expert ``e`` that ``tiles`` gives it, unpacked in VMEM
+    (``binary_qmm.expert_decode_qmm``): int32 (R, N).
+
+    ``w_packed`` is one layer's ``(E, K/32, N)`` words, or the ``(L, E, K/32,
+    N)`` stack of every layer with ``layer`` the one to read: the stack is
+    viewed as ``L * E`` experts and the tiles' ids are offset by ``layer *
+    E``, so a layer inside a scan reads its routed experts out of the stack
+    in place, with no copy of its slice.
+
+    The rows are laid out in ``tiles``' padded order and back; only the
+    experts of the tiles in use are read.  Zero padding of K, Kw and N is
+    exact, as in :func:`decode_qmm_int`; deepseek-v2-lite's experts need none.
+    """
+    r, k = a.shape
+    *stack, e, kw, n = w_packed.shape
+    tile_expert = tiles.tile_expert
+    if stack:
+        if layer is None:
+            raise ValueError(f"a stack of words {w_packed.shape} needs the layer to read")
+        w_packed = w_packed.reshape(-1, kw, n)
+        tile_expert = tile_expert + jnp.asarray(layer, jnp.int32) * e
+    bn, bkw = _bq.expert_block(kw, n)
+    a_p = jnp.concatenate([a, jnp.zeros((1, k), a.dtype)])[tiles.source]  # row R: zeros
+    a_p = _pad_to(a_p, 1, 32 * kw)
+    a_planes = _pad_to(a_p.reshape(a_p.shape[0], 4 * kw, 8).transpose(2, 0, 1), 2, 4 * bkw)
+    w_p = _pad_to(_pad_to(w_packed, 1, bkw), 2, bn)
+    out = _bq.expert_decode_qmm(
+        tile_expert, tiles.n_tiles, a_planes, w_p,
+        block=(bn, bkw), interpret=_auto_interpret(interpret),
+    )
+    return out[tiles.slot, :n]
 
 
 def popcount_qmm_int(

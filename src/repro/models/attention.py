@@ -792,8 +792,8 @@ def init_mla_cache(batch: int, max_len: int, cfg: ArchConfig) -> dict:
     return base
 
 
-def _mla_q(p, x, cfg, mode, positions):
-    """Project queries -> (q_nope (B,S,H,dn), q_rope (B,S,H,dr))."""
+def _mla_q(p, x, cfg, mode):
+    """Project queries -> (q_nope (B,S,H,dn), q_rope (B,S,H,dr)), before RoPE."""
     m, h = cfg.mla, cfg.n_heads
     qd = m.qk_nope_dim + m.qk_rope_dim
     if m.q_lora_rank:
@@ -803,9 +803,7 @@ def _mla_q(p, x, cfg, mode, positions):
     else:
         q = L.qlinear(p["q_proj"], x, cfg.quant, mode, name="attn.q")
     q = q.reshape(*x.shape[:-1], h, qd)
-    q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim :]
-    q_rope = L.rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim :]
 
 
 def mla_attention(
@@ -819,174 +817,181 @@ def mla_attention(
     """MLA mixer.  Prefill/train run the decompressed form; decode runs the
     *absorbed* form over the compressed (quantized) latent cache — the
     latent cache is both the memory win (kv_lora + rope per token instead of
-    2*H*dh) and the right operand of the serving act x act QMMs."""
-    m, h = cfg.mla, cfg.n_heads
-    b, s, _ = x.shape
-    quant = cfg.quant
-    scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_dim + m.qk_rope_dim))
+    2*H*dh) and the right operand of the serving act x act QMMs.
 
-    q_nope, q_rope = _mla_q(p, x, cfg, mode, positions)
+    The projections are QMM sites (``attn.q``, ``attn.kv_down``,
+    ``attn.k_rope``, ``attn.o``); everything between them runs under
+    ``jax.named_scope("attn.core")``, as in :func:`attention`."""
+    m, h = cfg.mla, cfg.n_heads
+    quant = cfg.quant
+
+    q_nope, q_rope = _mla_q(p, x, cfg, mode)
     ckv = L.qlinear(p["kv_down"], x, quant, mode, name="attn.kv_down")
     ckv = L.rmsnorm(p["kv_norm"], ckv, cfg.norm_eps)
     k_rope = L.qlinear(p["k_rope"], x, quant, mode, name="attn.k_rope")  # (B,S,dr)
-    k_rope = L.rope(k_rope, positions, cfg.rope_theta)
+    with jax.named_scope("attn.core"):
+        ctx, cache = _mla_core(p, x, q_nope, q_rope, ckv, k_rope, cfg, mode, positions, cache)
+    b, s, _ = x.shape
+    out = L.qlinear(
+        p["o"], ctx.reshape(b, s, h * m.v_head_dim).astype(x.dtype), quant, mode, name="attn.o"
+    )
+    return out, cache
+
+
+def _mla_core(p, x, q_nope, q_rope, ckv, k_rope, cfg, mode, positions, cache):
+    """Everything between the projections and ``attn.o``: RoPE (YaRN where
+    the config scales it), the latent cache write (``attn.cache``), the
+    scores with scale and mask (``attn.qk``: the ``k_up`` absorb, latent and
+    rope scores) and softmax with the latent PV and the ``v_up`` absorb
+    (``attn.av``).  Returns the context (B, S, H, dv) and the cache."""
+    m, h = cfg.mla, cfg.n_heads
+    b, s, _ = x.shape
+    quant = cfg.quant
+    scale = L.attention_scale(m.qk_nope_dim + m.qk_rope_dim, cfg.rope_scaling)
+    q_rope = L.rope(q_rope, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
+    k_rope = L.rope(k_rope, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
 
     decode = cache is not None and s == 1
     if cache is not None:
-        # per-row cursor: slots may sit at different sequence positions
-        pos = jnp.broadcast_to(jnp.reshape(cache["pos"], (-1,)), (b,))
-        quantized = "ckv_scale" in cache
-        row_write = jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(c, u, i, 0)
-        )
-        if quantized:
-            if s > 1:
-                sc, off = _calibrate_rows(ckv)
-            else:
-                sc = jnp.broadcast_to(jnp.reshape(cache["ckv_scale"], (-1,)), (b,))
-                off = jnp.broadcast_to(jnp.reshape(cache["ckv_offset"], (-1,)), (b,))
-            c_m = _quantize_to_cache(ckv, sc, off)
-            # rope slot dtype derives from the cache leaf (never a literal:
-            # a write/init mismatch is exactly the PR 6 drift class)
-            r_u = k_rope.astype(cache["k_rope"].dtype)
-            if decode:
-                new_ckv = row_write(cache["ckv"], c_m, pos)
-                new_rope = row_write(cache["k_rope"], r_u, pos)
-            else:
-                # prefill contract: fresh/uniform cache rows (row-0 cursor)
-                new_ckv = jax.lax.dynamic_update_slice_in_dim(
-                    cache["ckv"], c_m, pos[0], 1
-                )
-                new_rope = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k_rope"], r_u, pos[0], 1
-                )
-            cache = dict(
-                cache,
-                ckv=new_ckv,
-                ckv_scale=sc,
-                ckv_offset=off,
-                k_rope=new_rope,
-                pos=cache["pos"] + s,
-            )
-        else:
-            c_u = ckv.astype(cache["ckv"].dtype)
-            r_u = k_rope.astype(cache["k_rope"].dtype)
-            if decode:
-                new_ckv = row_write(cache["ckv"], c_u, pos)
-                new_rope = row_write(cache["k_rope"], r_u, pos)
-            else:
-                new_ckv = jax.lax.dynamic_update_slice_in_dim(
-                    cache["ckv"], c_u, pos[0], 1
-                )
-                new_rope = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k_rope"], r_u, pos[0], 1
-                )
-            cache = dict(cache, ckv=new_ckv, k_rope=new_rope, pos=cache["pos"] + s)
+        with jax.named_scope("attn.cache"):
+            cache = _mla_cache_write(cache, ckv, k_rope, b, s, decode)
 
     if decode:
         # ---- absorbed decode over the latent cache ----
         t = cache["ckv"].shape[1]
-        w_uk = p["k_up"]["w"] if "w" in p["k_up"] else None
-        if w_uk is None:
-            # serving params: dequantize the tiny up-projections once per
-            # step (kv_lora x H*dn — weight-bits packed); absorbed matmuls
-            # then run against the integer latent cache.
-            w_uk = _serving_dense(p["k_up"], m.kv_lora_rank, quant)
-            w_uv = _serving_dense(p["v_up"], m.kv_lora_rank, quant)
-        else:
-            w_uv = p["v_up"]["w"]
-        w_uk_h = w_uk.reshape(m.kv_lora_rank, h, m.qk_nope_dim)
-        # q_absorbed[b,1,h,r] = sum_dn q_nope[b,1,h,dn] * w_uk[r,h,dn]
-        q_abs = jnp.einsum(
-            "bshd,rhd->bshr", q_nope.astype(jnp.float32), w_uk_h.astype(jnp.float32)
-        )
         quantized = "ckv_scale" in cache
-        lat_backend = quant.backend_for("attn.qk_latent")
-        if quantized and quant.quantize_attention and (
-            _binary_scores_site(quant, "attn.qk_latent") is not None
-        ):
-            scores_lat = _scores_binary_latent(
-                q_abs,
-                cache["ckv"],
-                cache["ckv_scale"],
-                cache["ckv_offset"],
-                "attn.qk_latent",
-                lat_backend,
+        with jax.named_scope("attn.qk"):
+            w_uk = p["k_up"]["w"] if "w" in p["k_up"] else None
+            if w_uk is None:
+                # serving params: dequantize the tiny up-projection once per
+                # step (kv_lora x H*dn — weight-bits packed); the absorbed
+                # matmuls then run against the integer latent cache.
+                w_uk = _serving_dense(p["k_up"], m.kv_lora_rank, quant)
+            w_uk_h = w_uk.reshape(m.kv_lora_rank, h, m.qk_nope_dim)
+            # q_absorbed[b,1,h,r] = sum_dn q_nope[b,1,h,dn] * w_uk[r,h,dn]
+            q_abs = jnp.einsum(
+                "bshd,rhd->bshr", q_nope.astype(jnp.float32), w_uk_h.astype(jnp.float32)
             )
-        elif quantized and quant.quantize_attention:
-            scores_lat = _scores_int_latent(
-                q_abs,
-                cache["ckv"],
-                cache["ckv_scale"],
-                cache["ckv_offset"],
-                quant.attn_act_bits,
-                lat_backend,
-            )
-        else:
-            ckv_all = cache["ckv"]
-            if quantized:
-                ckv_all = _dequantize_from_cache(
-                    ckv_all, cache["ckv_scale"], cache["ckv_offset"], jnp.float32
+            lat_backend = quant.backend_for("attn.qk_latent")
+            if quantized and quant.quantize_attention and (
+                _binary_scores_site(quant, "attn.qk_latent") is not None
+            ):
+                scores_lat = _scores_binary_latent(
+                    q_abs,
+                    cache["ckv"],
+                    cache["ckv_scale"],
+                    cache["ckv_offset"],
+                    "attn.qk_latent",
+                    lat_backend,
                 )
-            scores_lat = jnp.einsum(
-                "bshr,btr->bhst", q_abs, ckv_all.astype(jnp.float32)
-            )
-        scores_rope = jnp.einsum(
-            "bshd,btd->bhst",
-            q_rope.astype(jnp.float32),
-            cache["k_rope"].astype(jnp.float32),
-        )
-        scores = (scores_lat + scores_rope) * scale
-        valid = jnp.arange(t)[None, :] < jnp.reshape(cache["pos"], (-1, 1))
-        scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)  # (B,H,1,T)
-        if quantized and quant.quantize_attention:
-            ctx_lat = _pv_int_latent(
-                probs, cache["ckv"], cache["ckv_scale"], cache["ckv_offset"]
-            )
-        else:
-            ckv_all = cache["ckv"]
-            if quantized:
-                ckv_all = _dequantize_from_cache(
-                    ckv_all, cache["ckv_scale"], cache["ckv_offset"], jnp.float32
+            elif quantized and quant.quantize_attention:
+                scores_lat = _scores_int_latent(
+                    q_abs,
+                    cache["ckv"],
+                    cache["ckv_scale"],
+                    cache["ckv_offset"],
+                    quant.attn_act_bits,
+                    lat_backend,
                 )
-            ctx_lat = jnp.einsum("bhst,btr->bshr", probs, ckv_all.astype(jnp.float32))
-        w_uv_h = w_uv.reshape(m.kv_lora_rank, h, m.v_head_dim)
-        ctx = jnp.einsum("bshr,rhd->bshd", ctx_lat, w_uv_h.astype(jnp.float32))
-        out = L.qlinear(
-            p["o"],
-            ctx.reshape(b, s, h * m.v_head_dim).astype(x.dtype),
-            quant,
-            mode,
-            name="attn.o",
-        )
-        return out, cache
+            else:
+                ckv_all = cache["ckv"]
+                if quantized:
+                    ckv_all = _dequantize_from_cache(
+                        ckv_all, cache["ckv_scale"], cache["ckv_offset"], jnp.float32
+                    )
+                scores_lat = jnp.einsum(
+                    "bshr,btr->bhst", q_abs, ckv_all.astype(jnp.float32)
+                )
+            scores_rope = jnp.einsum(
+                "bshd,btd->bhst",
+                q_rope.astype(jnp.float32),
+                cache["k_rope"].astype(jnp.float32),
+            )
+            scores = (scores_lat + scores_rope) * scale
+            valid = jnp.arange(t)[None, :] < jnp.reshape(cache["pos"], (-1, 1))
+            scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
+        with jax.named_scope("attn.av"):
+            probs = jax.nn.softmax(scores, axis=-1)  # (B,H,1,T)
+            if quantized and quant.quantize_attention:
+                ctx_lat = _pv_int_latent(
+                    probs, cache["ckv"], cache["ckv_scale"], cache["ckv_offset"]
+                )
+            else:
+                ckv_all = cache["ckv"]
+                if quantized:
+                    ckv_all = _dequantize_from_cache(
+                        ckv_all, cache["ckv_scale"], cache["ckv_offset"], jnp.float32
+                    )
+                ctx_lat = jnp.einsum("bhst,btr->bshr", probs, ckv_all.astype(jnp.float32))
+            w_uv = p["v_up"]["w"] if "w" in p["v_up"] else None
+            if w_uv is None:
+                w_uv = _serving_dense(p["v_up"], m.kv_lora_rank, quant)
+            w_uv_h = w_uv.reshape(m.kv_lora_rank, h, m.v_head_dim)
+            ctx = jnp.einsum("bshr,rhd->bshd", ctx_lat, w_uv_h.astype(jnp.float32))
+        return ctx, cache
 
     # ---- decompressed prefill / train ----
     sdt = jnp.bfloat16 if cfg.attn_scores_dtype == "bf16" else jnp.float32
-    k_nope = L.qlinear(
-        p["k_up"], ckv, quant, mode, name="attn.k_up"
-    ).reshape(b, s, h, m.qk_nope_dim)
-    v = L.qlinear(
-        p["v_up"], ckv, quant, mode, name="attn.v_up"
-    ).reshape(b, s, h, m.v_head_dim)
-    if mode == "train" and quant.enabled and quant.quantize_attention:
-        q_nope = Q.fake_quant(q_nope, quant.attn_act_bits)
-        k_nope = Q.fake_quant(k_nope, quant.attn_act_bits)
-    scores = (
-        jnp.einsum("bshd,bthd->bhst", q_nope.astype(sdt), k_nope.astype(sdt))
-        + jnp.einsum("bshd,btd->bhst", q_rope.astype(sdt), k_rope.astype(sdt))
-    ) * sdt(scale)
-    mask = _mask(s, s, positions[0, 0] * 0, cfg.causal, 0)
-    scores = scores + mask[None, None].astype(sdt)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if mode == "train" and quant.enabled and quant.quantize_attention:
-        probs = Q.fake_quant(probs, quant.attn_act_bits)
-    ctx = jnp.einsum("bhst,bthd->bshd", probs.astype(x.dtype), v)
-    out = L.qlinear(
-        p["o"], ctx.reshape(b, s, h * m.v_head_dim), quant, mode, name="attn.o"
+    with jax.named_scope("attn.qk"):
+        k_nope = L.qlinear(
+            p["k_up"], ckv, quant, mode, name="attn.k_up"
+        ).reshape(b, s, h, m.qk_nope_dim)
+        if mode == "train" and quant.enabled and quant.quantize_attention:
+            q_nope = Q.fake_quant(q_nope, quant.attn_act_bits)
+            k_nope = Q.fake_quant(k_nope, quant.attn_act_bits)
+        scores = (
+            jnp.einsum("bshd,bthd->bhst", q_nope.astype(sdt), k_nope.astype(sdt))
+            + jnp.einsum("bshd,btd->bhst", q_rope.astype(sdt), k_rope.astype(sdt))
+        ) * sdt(scale)
+        mask = _mask(s, s, positions[0, 0] * 0, cfg.causal, 0)
+        scores = scores + mask[None, None].astype(sdt)
+    with jax.named_scope("attn.av"):
+        v = L.qlinear(
+            p["v_up"], ckv, quant, mode, name="attn.v_up"
+        ).reshape(b, s, h, m.v_head_dim)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if mode == "train" and quant.enabled and quant.quantize_attention:
+            probs = Q.fake_quant(probs, quant.attn_act_bits)
+        ctx = jnp.einsum("bhst,bthd->bshd", probs.astype(x.dtype), v)
+    return ctx, cache
+
+
+def _mla_cache_write(cache, ckv, k_rope, b, s, decode):
+    """Write the step's latent rows: the int8 grid calibrated on a prompt (per
+    row), or the slot's stored grid for a decode token; rope keys as the
+    cache leaf's dtype."""
+    # per-row cursor: slots may sit at different sequence positions
+    pos = jnp.broadcast_to(jnp.reshape(cache["pos"], (-1,)), (b,))
+    row_write = jax.vmap(
+        lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(c, u, i, 0)
     )
-    return out, cache
+
+    def write(leaf, rows):
+        if decode:
+            return row_write(leaf, rows, pos)
+        # prefill contract: fresh/uniform cache rows (row-0 cursor)
+        return jax.lax.dynamic_update_slice_in_dim(leaf, rows, pos[0], 1)
+
+    # rope slot dtype derives from the cache leaf (never a literal:
+    # a write/init mismatch is how the cache dtype drifted once)
+    new_rope = write(cache["k_rope"], k_rope.astype(cache["k_rope"].dtype))
+    if "ckv_scale" not in cache:
+        new_ckv = write(cache["ckv"], ckv.astype(cache["ckv"].dtype))
+        return dict(cache, ckv=new_ckv, k_rope=new_rope, pos=cache["pos"] + s)
+    if s > 1:
+        sc, off = _calibrate_rows(ckv)
+    else:
+        sc = jnp.broadcast_to(jnp.reshape(cache["ckv_scale"], (-1,)), (b,))
+        off = jnp.broadcast_to(jnp.reshape(cache["ckv_offset"], (-1,)), (b,))
+    new_ckv = write(cache["ckv"], _quantize_to_cache(ckv, sc, off))
+    return dict(
+        cache,
+        ckv=new_ckv,
+        ckv_scale=sc,
+        ckv_offset=off,
+        k_rope=new_rope,
+        pos=cache["pos"] + s,
+    )
 
 
 def _pv_int_latent(p_probs, ckv_m, ckv_scale, ckv_offset):

@@ -17,12 +17,14 @@ are produced from train params by ``prepare_serving_params`` (model_zoo).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import ArchConfig, QuantConfig
+from repro.configs.base import ArchConfig, QuantConfig, RopeScaling
 from repro.core import flow_abstraction as FA
 from repro.core import packing
 from repro.core import qmm as QE
@@ -36,6 +38,9 @@ __all__ = [
     "rmsnorm",
     "layernorm",
     "rope",
+    "rope_inv_freq",
+    "yarn_mscale",
+    "attention_scale",
     "ffn",
     "init_ffn",
     "embed",
@@ -176,17 +181,71 @@ def layernorm(p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)).astype(x.dtype)
 
 
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature term as DeepSeek publishes it:
+    ``0.1 * mscale * ln(scale) + 1`` (1 when nothing is scaled)."""
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def rope_inv_freq(d: int, theta: float, scaling: RopeScaling) -> np.ndarray:
+    """YaRN's ``d/2`` inverse frequencies (float32).
+
+    ``extra_i = theta^(-2i/d)`` and ``inter_i = extra_i / factor`` are joined
+    by ``ramp_i = clip((i - low) / (high - low), 0, 1)``, where ``low`` and
+    ``high`` are the dims at which a wavelength fits ``beta_fast`` and
+    ``beta_slow`` times into the original context:
+    ``inv_freq_i = inter_i * ramp_i + extra_i * (1 - ramp_i)``.
+    """
+    i = np.arange(d // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / d)
+
+    def dim_of(rotations: float) -> float:
+        n = scaling.original_max_position_embeddings
+        return d * math.log(n / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), d - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / scaling.factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def attention_scale(head_dim: int, scaling: Optional[RopeScaling]) -> float:
+    """Softmax scale ``head_dim^-0.5``, times ``yarn_mscale(factor,
+    mscale_all_dim)^2`` under YaRN."""
+    scale = head_dim**-0.5
+    if scaling is not None and scaling.mscale_all_dim:
+        scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+    return scale
+
+
 def rope(
-    x: jax.Array, positions: jax.Array, theta: float, dtype=jnp.float32
+    x: jax.Array,
+    positions: jax.Array,
+    theta: float,
+    dtype=jnp.float32,
+    scaling: Optional[RopeScaling] = None,
 ) -> jax.Array:
-    """Rotary embedding. x: (..., S, H, D) or (..., S, D); positions (..., S)."""
+    """Rotary embedding. x: (..., S, H, D) or (..., S, D); positions (..., S).
+
+    ``scaling`` (YaRN) sets the frequencies by :func:`rope_inv_freq` and
+    multiplies cos and sin by ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(rope_inv_freq(d, theta, scaling))
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, half)
     if x.ndim == angles.ndim + 1:  # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

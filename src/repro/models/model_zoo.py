@@ -351,6 +351,7 @@ def prefill(
     cache: dict,
     frontend: Optional[jax.Array] = None,
     length: Optional[jax.Array] = None,
+    compiled_prefix: bool = False,
 ) -> Tuple[jax.Array, dict]:
     """Process the prompt; returns (last-position logits (B,V), cache).
 
@@ -367,6 +368,9 @@ def prefill(
     padded length reaches the window, and SSM recurrences integrate pad
     steps.  Exact-length prefill (``length=None``, no padding) is the
     default and what the continuous-batching engine uses.
+
+    ``compiled_prefix``: an eager caller's unrolled prefix blocks each run as
+    one compiled program (``transformer.stack_apply``).
     """
     with dispatch.tuning_phase("prefill"):
         b, s = tokens.shape
@@ -381,7 +385,8 @@ def prefill(
         x = _embed_inputs(params, tokens, cfg, positions, frontend, "serve")
         x = x.astype(jnp.bfloat16)
         x, new_stack, _ = T.stack_apply(
-            params["stack"], x, cfg, "serve", positions, cache["stack"], encoder_out
+            params["stack"], x, cfg, "serve", positions, cache["stack"], encoder_out,
+            compiled_prefix=compiled_prefix,
         )
         if length is None:
             x_last = x[:, -1:]

@@ -133,6 +133,9 @@ def block_apply(
     return x + out, cache, aux
 
 
+_compiled_block = jax.jit(block_apply, static_argnums=(2, 3, 4))
+
+
 # ---------------------------------------------------------------------------
 # the stack: prefix (unrolled) + period (scanned)
 # ---------------------------------------------------------------------------
@@ -191,16 +194,23 @@ def stack_apply(
     positions: jax.Array,
     caches: Optional[dict] = None,
     encoder_out: Optional[jax.Array] = None,
+    compiled_prefix: bool = False,
 ):
     """Apply prefix blocks then the scanned period stack.
+
+    ``compiled_prefix``: run each unrolled prefix block as one compiled
+    program, for a caller that runs the stack eagerly (the serving engine's
+    prefill): dispatched op by op, each op at a new prompt length is a
+    compile of its own (173 for deepseek-v2-lite's dense layer).
 
     Returns (x, new_caches, aux_total).
     """
     aux_total = jnp.float32(0.0)
     new_prefix = []
+    prefix_block = _compiled_block if compiled_prefix else block_apply
     for i, kind in enumerate(cfg.prefix_layers):
         c = caches["prefix"][i] if caches is not None else None
-        x, c, aux = block_apply(
+        x, c, aux = prefix_block(
             params["prefix"][i], x, cfg, kind, mode, positions, c, encoder_out
         )
         new_prefix.append(c)
@@ -208,6 +218,14 @@ def stack_apply(
 
     new_period = [None] * len(cfg.pattern_period)
     if cfg.n_periods:
+        xs = {"params": params["period"]}
+        # Serving: the routed experts' words stay whole, read in place by the
+        # grouped kernel at the step's layer, not sliced (copied) per layer.
+        held = [None] * len(cfg.pattern_period)
+        if mode == "serve":
+            scanned, held = M.hold_expert_words(params["period"])
+            if any(h is not None for h in held):
+                xs = {"params": scanned, "layer": jnp.arange(cfg.n_periods)}
 
         def body(carry, xs):
             xc, aux_c = carry
@@ -216,15 +234,17 @@ def stack_apply(
             new_cs = []
             for j, kind in enumerate(cfg.pattern_period):
                 cj = c_stk[j] if c_stk is not None else None
+                pj = p_stk[j]
+                if held[j] is not None:
+                    pj = M.lend_expert_words(pj, held[j], xs["layer"])
                 xc, cj, aux = block_apply(
-                    p_stk[j], xc, cfg, kind, mode, positions, cj, encoder_out
+                    pj, xc, cfg, kind, mode, positions, cj, encoder_out
                 )
                 new_cs.append(cj if cj is not None else 0)
                 aux_c = aux_c + aux
             ys = {"caches": new_cs} if c_stk is not None else {}
             return (xc, aux_c), ys
 
-        xs = {"params": params["period"]}
         if caches is not None:
             xs["caches"] = caches["period"]
         # Block-level remat for QAT training: recompute the period body on

@@ -392,7 +392,9 @@ class ServeEngine:
         slot_cache = Z.init_slot_cache(self.max_len, self.cfg)
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
         with self._span("prefill", rid=req.rid), jax.named_scope("prefill"):
-            logits, slot_cache = Z.prefill(self.params, tokens, self.cfg, slot_cache)
+            logits, slot_cache = Z.prefill(
+                self.params, tokens, self.cfg, slot_cache, compiled_prefix=True
+            )
         with self._span("insert", rid=req.rid):
             cache = Z.cache_insert(cache, slot_cache, slot)
         with self._span("fetch", rid=req.rid):
@@ -843,7 +845,7 @@ def serve_sequential(
         rng = _request_rng(seed, rid)
         cache = Z.init_cache(1, max_len, cfg)
         tokens = jnp.asarray(np.asarray(r.prompt, np.int32)[None, :])
-        logits, cache = Z.prefill(params, tokens, cfg, cache)
+        logits, cache = Z.prefill(params, tokens, cfg, cache, compiled_prefix=True)
         tok = _sample(np.asarray(logits)[0], r.temperature, rng)
         r.output = [tok]
         while len(r.output) < r.max_new_tokens:

@@ -8,6 +8,10 @@ not merely pass in interpret mode), the one-layer-at-a-time weight setup,
 and one decode step, whose memory must fit the chip's HBM and whose QMM
 sites must read the packed weights in the decode kernel.
 
+The routed-expert path gets the same at deepseek-v2-lite's widths: the
+grouped expert kernel, and a decode step that holds no unpacked expert
+weights.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.  The persistent compilation cache is off around the compiles (an
@@ -98,6 +102,26 @@ def test_kernel_lowers_to_mosaic(one_chip, kernel, site):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# deepseek-v2-lite's experts at 16 slots x top-6 rows: gate/up (2048 -> 1408) and down
+EXPERTS = {"gate": (96, 64, 2048, 1408), "down": (96, 64, 1408, 2048)}
+
+
+@pytest.mark.parametrize("site", sorted(EXPERTS))
+def test_expert_kernel_lowers_to_mosaic(one_chip, site):
+    r, e, k, n = EXPERTS[site]
+
+    def grouped(a, row_expert, w):
+        return ops.expert_decode_qmm_int(a, ops.expert_tiles(row_expert, e), w, interpret=False)
+
+    args = (
+        _sds(one_chip, (r, k), jnp.int8),
+        _sds(one_chip, (r,), jnp.int32),
+        _sds(one_chip, (e, k // 32, n), jnp.uint32),
+    )
+    compiled = jax.jit(grouped).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def _bytes(compiled) -> float:
     m = compiled.memory_analysis()
     return (
@@ -165,3 +189,39 @@ def test_granite_decode_step_unpacks_weights_in_vmem(one_chip, monkeypatch):
         if shape not in stacked:  # the packed weights themselves
             assert np.prod(shape) < smallest_site, shape
     assert compiled.memory_analysis().temp_size_in_bytes < 235e6
+
+
+def test_deepseek_decode_step_reads_only_packed_experts(one_chip, monkeypatch):
+    """deepseek-v2-lite at 16 slots: the routed experts run in the grouped
+    kernel, and no array of the compiled step is an unpacked (E, K, N)
+    expert weight, int8 or u32, nor a copy of the packed words, one layer's
+    (E, K/32, N) sliced out of the stack or the stack relaid whole: the
+    kernel reads the stack in place.  The step fits the chip."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # lowered for the v5e
+    cfg = get_config("deepseek-v2-lite-16b")
+    shapes = jax.eval_shape(
+        lambda k: Z.init_serving_params(k, cfg), jax.random.PRNGKey(0)
+    )
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(one_chip, a.shape, a.dtype), t
+    )
+    cache = place(jax.eval_shape(lambda: Z.init_cache(16, 4096, cfg)))
+    compiled = (
+        jax.jit(lambda p, t, c: Z.decode_step(p, t, cfg, c))
+        .lower(place(shapes), _sds(one_chip, (16,), jnp.int32), cache)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert re.search(r"%expert_decode_qmm[.\d]* = .*tpu_custom_call", text)
+    e, d, f = cfg.moe.n_routed, cfg.d_model, cfg.moe.d_expert_ff
+    one_expert = d * f
+    for dtype, dims in re.findall(r"(u32|s8|s32)\[([0-9,]+)\]", text):
+        shape = tuple(int(n) for n in dims.split(","))
+        # the packed words of all 26 layers, (26, 64, K/32, N), hold fewer
+        # elements than one layer's (64, K, N) weights unpacked
+        assert not (e in shape and np.prod(shape) >= e * one_expert), (dtype, shape)
+    layer_words = e * (f // 32) * d  # the fewest of a layer's gate, up or down
+    for dims, op in re.findall(r"= u32\[([0-9,]+)\]\{[^}]*\} ([\w-]+)\(", text):
+        if op not in ("parameter", "bitcast", "get-tuple-element"):
+            assert np.prod([int(n) for n in dims.split(",")]) < layer_words, (dims, op)
+    assert _bytes(compiled) < HBM_BYTES
