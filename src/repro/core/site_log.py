@@ -54,9 +54,11 @@ def record(**fields) -> None:
       cfg_bits: the precision QuantConfig declares for this site class
       mantissa_dtype: str dtype of the quantized mantissa fed to the engine
       backend: resolved backend string (qlinear sites only)
-      int_core: "packed" where the ``mxu`` backend's decode kernel read the
-        packed weight and unpacked it in VMEM, else "unpacked" (qlinear
-        sites only; ``qmm.int_core``)
+      int_core: on qlinear sites, "packed" where the ``mxu`` backend's
+        decode kernel read the packed weight and unpacked it in VMEM, else
+        "unpacked" (``qmm.int_core``); on attention sites, "decode_attn"
+        where single-token decode ran in the ``decode_attn`` kernel
+        (``attention.decode_core_engages``), else "xla"
     """
     log = _LOG.get()
     if log is not None:

@@ -19,6 +19,7 @@ from repro.core.quantization import QuantTensor
 from repro.kernels import binary_attn as _ba
 from repro.kernels import binary_qmm as _bq
 from repro.kernels import bitserial_qmm as _bs
+from repro.kernels import decode_attn as _da
 from repro.kernels import fused_qmm as _fq
 from repro.kernels import popcount_qmm as _pq
 
@@ -29,6 +30,7 @@ __all__ = [
     "ExpertTiles",
     "expert_tiles",
     "expert_decode_qmm_int",
+    "decode_attn",
     "popcount_qmm_int",
     "bitserial_qmm_int",
     "qmm_pallas",
@@ -200,6 +202,62 @@ def expert_decode_qmm_int(
         block=(bn, bkw), interpret=_auto_interpret(interpret),
     )
     return out[tiles.slot, :n]
+
+
+def decode_attn(
+    q_mantissa: jax.Array,
+    q_scale: jax.Array,
+    q_offset: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    k_scale: jax.Array,
+    k_offset: jax.Array,
+    v_scale: jax.Array,
+    v_offset: jax.Array,
+    pos: jax.Array,
+    *,
+    block_t: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Single-token decode attention over the int8 GQA cache, in one kernel
+    (``decode_attn.decode_attn``): float32 context ``(B, H, dh)``.
+
+    ``q_mantissa`` ``(B, H, dh)`` int8 and its per-slot ``q_scale`` and
+    ``q_offset`` ``(B,)`` are q on its re-centered grid; ``k`` and ``v``
+    ``(B, T, kvH, dh)`` the cache's re-centered int8 mantissas with their
+    per-slot affines ``(B,)``; ``pos`` ``(B,)`` each slot's last live row.
+    ``block_t`` rows are read a grid step, ``decode_attn.block_rows(T)`` by
+    default.
+    The epilogue constants are the products ``_scores_int`` and ``_pv_int``
+    form, in the same order, so the kernel's affine terms are theirs.
+    """
+    b, h, dh = q_mantissa.shape
+    hq = -(-(h + 1) // 32) * 32
+    q_aug = jnp.concatenate(
+        [
+            q_mantissa.astype(jnp.int8),
+            jnp.ones((b, 1, dh), jnp.int8),
+            jnp.zeros((b, hq - h - 1, dh), jnp.int8),
+        ],
+        axis=1,
+    )
+    f32 = lambda x: jnp.reshape(jnp.asarray(x, jnp.float32), (b,))  # noqa: E731
+    a1, g1 = f32(q_scale), f32(q_offset)
+    a2 = f32(k_scale)
+    g2 = f32(k_offset) + 128.0 * a2
+    pa1, pg1 = jnp.float32(1.0 / 255.0), jnp.float32(128.0 / 255.0)
+    va2 = f32(v_scale)
+    vg2 = f32(v_offset) + 128.0 * va2
+    aff = jnp.stack(
+        [a1 * a2, a1 * g2, g1 * a2, g1 * g2, pa1 * va2, pa1 * vg2, pg1 * va2, pg1 * vg2],
+        axis=1,
+    )
+    pos = jnp.reshape(jnp.asarray(pos, jnp.int32), (b,))
+    out = _da.decode_attn(
+        pos, aff, q_aug, k, v, n_heads=h,
+        block_t=block_t or _da.block_rows(k.shape[1]), interpret=_auto_interpret(interpret),
+    )
+    return out[:, :h]
 
 
 def popcount_qmm_int(

@@ -31,6 +31,7 @@ from repro.core import backend_registry, packing
 from repro.core import flow_abstraction as FA
 from repro.core import quantization as Q
 from repro.core import site_log
+from repro.kernels import decode_attn as K_da
 from repro.kernels import ops as K_ops
 from repro.models import layers as L
 
@@ -41,6 +42,7 @@ __all__ = [
     "init_mla",
     "mla_attention",
     "init_mla_cache",
+    "decode_core_engages",
 ]
 
 _NEG_INF = -1e30
@@ -253,6 +255,7 @@ def _scores_binary(q, k_planes_t, k_scale, k_offset, dh: int, site: str, backend
             bits=1,
             mantissa_dtype=str(qq.mantissa.dtype),
             backend=backend,
+            int_core="xla",
         )
     q_planes = _pack_q_heads(qq.mantissa)  # (B,H,S,dw)
     counts = K_ops.binary_attn_scores(
@@ -286,6 +289,7 @@ def _scores_binary_latent(q_abs, ckv_m, ckv_scale, ckv_offset, site: str, backen
             bits=1,
             mantissa_dtype=str(qq.mantissa.dtype),
             backend=backend,
+            int_core="xla",
         )
     q_planes = _pack_q_heads(qq.mantissa)  # (B,H,S,rw)
     k_bits = (ckv_m >= 0).astype(jnp.uint32)
@@ -385,6 +389,23 @@ def _int_einsum(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.einsum(spec, a, b, preferred_element_type=jnp.int32)
 
 
+def _quantize_q(q, attn_bits: int, backend: str, int_core: str) -> Q.QuantTensor:
+    """q on its re-centered per-slot grid (axis 0 kept: co-batched slots stay
+    independent), and the ``attn.qk`` site record of the core that takes it."""
+    qq = Q.quantize_activation(q.astype(jnp.float32), attn_bits, per_channel_axis=0)
+    qr = Q.recenter(qq)
+    if site_log.is_recording():
+        site_log.record(
+            kind="attn",
+            site="attn.qk",
+            bits=attn_bits,
+            mantissa_dtype=str(qr.mantissa.dtype),
+            backend=backend,
+            int_core=int_core,
+        )
+    return qr
+
+
 def _scores_int(q, k_mantissa, k_scale, k_offset, attn_bits: int, backend: str = "auto"):
     """Integer QK^T via the flow abstraction (act x act QMM, paper type 2),
     GROUPED over kv heads (k stays un-expanded and kv-sharded; no dim
@@ -398,17 +419,7 @@ def _scores_int(q, k_mantissa, k_scale, k_offset, attn_bits: int, backend: str =
     b, s, h, dh = q.shape
     t, kvh = k_mantissa.shape[1], k_mantissa.shape[2]
     g = h // kvh
-    # per-row calibration (axis 0 kept): co-batched slots stay independent
-    qq = Q.quantize_activation(q.astype(jnp.float32), attn_bits, per_channel_axis=0)
-    qr = Q.recenter(qq)
-    if site_log.is_recording():
-        site_log.record(
-            kind="attn",
-            site="attn.qk",
-            bits=attn_bits,
-            mantissa_dtype=str(qr.mantissa.dtype),
-            backend=backend,
-        )
+    qr = _quantize_q(q, attn_bits, backend, "xla")
     x1 = qr.mantissa.reshape(b, s, kvh, g, dh)  # int8
     x2 = k_mantissa.astype(jnp.int8)  # (B,T,kvH,dh)
     xy = _int_einsum("bskgd,btkd->bkgst", x1, x2).astype(jnp.float32)
@@ -423,6 +434,64 @@ def _scores_int(q, k_mantissa, k_scale, k_offset, attn_bits: int, backend: str =
     col = col.transpose(0, 2, 1)[:, :, None, None, :]  # (B,kvH,1,1,T)
     out = xy * (a1 * a2) + (a1 * g2) * row + (g1 * a2) * col + g1 * g2 * dh
     return out.reshape(b, h, s, t)
+
+
+def decode_core_engages(
+    cache: Optional[dict],
+    cfg: ArchConfig,
+    kind: str,
+    mode: str,
+    s: int,
+    kv_override=None,
+) -> bool:
+    """Does a decode step over ``cache`` run in the ``decode_attn`` kernel
+    (``kernels.decode_attn``, which reads each slot's live K and V rows once)
+    rather than the XLA chain ``_scores_int`` -> mask -> softmax ->
+    ``_pv_int`` over all ``max_len`` rows?
+
+    Yes for one query token (``s == 1``) in serve mode with int8 attention,
+    against a global int8 GQA cache (no ring buffer, no window) read in
+    grouped mode, at shapes the kernel takes (``dh`` a multiple of 128, kv
+    heads a multiple of 8, a slot's scores and V rows within its VMEM
+    budget), on a TPU.  Prefill, packed-K (bitwise) and float caches, local
+    layers, ``gqa_mode="expand"``, cross-attention and CPU runs keep the XLA
+    chain; MLA's latent cache never comes here.
+    """
+    quant = cfg.quant
+    if not (
+        s == 1
+        and mode == "serve"
+        and kv_override is None
+        and quant.enabled
+        and quant.quantize_attention
+        and _cache_quantized(cache)
+        and cache["k"].dtype == jnp.int8
+        and not (kind == "l" and cfg.window_size)
+        and cfg.gqa_mode != "expand"
+    ):
+        return False
+    _, t, kvh, dh = cache["k"].shape
+    return (
+        dh % 128 == 0
+        and kvh % 8 == 0
+        and K_da.fits(t, kvh, dh, cfg.n_heads)
+        and K_ops.on_tpu()
+    )
+
+
+def _decode_attn_int(
+    q, k_mantissa, v_mantissa, k_scale, k_offset, v_scale, v_offset, pos,
+    attn_bits: int, backend: str,
+):
+    """Decode attention in one kernel (``ops.decode_attn``): q on the grid
+    ``_scores_int`` puts it on, against each slot's live cache rows.
+    Returns the context (B, 1, H, dh) in float32, as ``_pv_int`` does."""
+    qr = _quantize_q(q, attn_bits, backend, "decode_attn")
+    ctx = K_ops.decode_attn(
+        qr.mantissa[:, 0], qr.scale, qr.offset, k_mantissa, v_mantissa,
+        k_scale, k_offset, v_scale, v_offset, pos,
+    )
+    return ctx[:, None]
 
 
 def _write_prefill_cache(
@@ -476,6 +545,7 @@ def _scores_int_latent(
             bits=attn_bits,
             mantissa_dtype=str(qr.mantissa.dtype),
             backend=backend,
+            int_core="xla",
         )
     x1 = qr.mantissa.reshape(b, s * h, r)
     x2 = jnp.swapaxes(ckv_m, -1, -2).astype(jnp.int8)  # (b, r, t)
@@ -705,6 +775,13 @@ def _attention_core(p, x, q, k, v, cfg, kind, mode, positions, cache, kv_overrid
             new_v = row_write(cache["v"], v_m, slot)
             new_cache = dict(cache, k=new_k, v=new_v, pos=cache["pos"] + 1)
 
+        if decode_core_engages(cache, cfg, kind, mode, s, kv_override):
+            with jax.named_scope("attn.qk"):
+                ctx = _decode_attn_int(
+                    q, new_k, new_v, k_sc, k_off, v_sc, v_off, pos,
+                    quant.attn_act_bits, qk_backend,
+                )
+            return ctx, new_cache
         with jax.named_scope("attn.qk"):
             t = cache_len
             posc = pos[:, None]  # (B, 1)

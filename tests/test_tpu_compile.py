@@ -5,8 +5,9 @@ for a described topology.  These tests compile the main serving path at
 granite-8b's published widths: each Pallas kernel at the granite-8b FFN
 shapes (it must lower to Mosaic — a ``tpu_custom_call`` in the program —
 not merely pass in interpret mode), the one-layer-at-a-time weight setup,
-and one decode step, whose memory must fit the chip's HBM and whose QMM
-sites must read the packed weights in the decode kernel.
+and one decode step, whose memory must fit the chip's HBM, whose QMM
+sites must read the packed weights in the decode kernel, and whose decode
+attention must read the int8 cache as stored, in the ``decode_attn`` kernel.
 
 The routed-expert path gets the same at deepseek-v2-lite's widths: the
 grouped expert kernel, and a decode step that holds no unpacked expert
@@ -122,6 +123,24 @@ def test_expert_kernel_lowers_to_mosaic(one_chip, site):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# granite-8b's decode attention: 8 slots over 4096 cache rows, 32/8 heads of 128
+ATTN = {"slots": 8, "rows": 4096, "heads": 32, "kv_heads": 8, "dh": 128}
+
+
+def test_decode_attn_lowers_to_mosaic(one_chip):
+    b, t, h, kvh, dh = (ATTN[k] for k in ("slots", "rows", "heads", "kv_heads", "dh"))
+
+    def attend(qm, qs, qo, k, v, ks, ko, vs, vo, pos):
+        return ops.decode_attn(qm, qs, qo, k, v, ks, ko, vs, vo, pos, interpret=False)
+
+    f32 = _sds(one_chip, (b,), jnp.float32)
+    cache = _sds(one_chip, (b, t, kvh, dh), jnp.int8)
+    args = (_sds(one_chip, (b, h, dh), jnp.int8), f32, f32, cache, cache, f32, f32, f32, f32,
+            _sds(one_chip, (b,), jnp.int32))
+    compiled = jax.jit(attend).lower(*args).compile()
+    assert re.search(r"%decode_attn[.\d]* = .*tpu_custom_call", compiled.as_text())
+
+
 def _bytes(compiled) -> float:
     m = compiled.memory_analysis()
     return (
@@ -158,12 +177,10 @@ def test_granite_decode_step_fits_one_chip(one_chip):
     assert _bytes(compiled) < HBM_BYTES
 
 
-def test_granite_decode_step_unpacks_weights_in_vmem(one_chip, monkeypatch):
-    """At 8 slots every QMM site runs the decode kernel on the packed words:
-    no u32 broadcast or int8 copy of a site's K x N weight reaches HBM (the
-    XLA unpack path's temporaries are 539 MB, its ffn u32 broadcast alone
-    235 MB)."""
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # lowered for the v5e
+@pytest.fixture(scope="module")
+def granite_decode(one_chip):
+    """granite-8b's decode step at 8 slots x 4096 rows, compiled for the v5e
+    with the TPU's choices (``ops.on_tpu`` patched), and its weight shapes."""
     cfg = get_config("granite-8b")
     shapes = jax.eval_shape(
         lambda k: Z.init_serving_params(k, cfg), jax.random.PRNGKey(0)
@@ -171,12 +188,23 @@ def test_granite_decode_step_unpacks_weights_in_vmem(one_chip, monkeypatch):
     place = lambda t: jax.tree.map(  # noqa: E731
         lambda a: _sds(one_chip, a.shape, a.dtype), t
     )
-    cache = place(jax.eval_shape(lambda: Z.init_cache(8, 4096, cfg)))
-    compiled = (
-        jax.jit(lambda p, t, c: Z.decode_step(p, t, cfg, c))
-        .lower(place(shapes), _sds(one_chip, (8,), jnp.int32), cache)
-        .compile()
-    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)  # lowered for the v5e
+        cache = place(jax.eval_shape(lambda: Z.init_cache(ATTN["slots"], ATTN["rows"], cfg)))
+        compiled = (
+            jax.jit(lambda p, t, c: Z.decode_step(p, t, cfg, c))
+            .lower(place(shapes), _sds(one_chip, (ATTN["slots"],), jnp.int32), cache)
+            .compile()
+        )
+    return cfg, shapes, compiled
+
+
+def test_granite_decode_step_unpacks_weights_in_vmem(granite_decode):
+    """At 8 slots every QMM site runs the decode kernel on the packed words:
+    no u32 broadcast or int8 copy of a site's K x N weight reaches HBM (the
+    XLA unpack path's temporaries are 539 MB, its ffn u32 broadcast alone
+    235 MB)."""
+    cfg, shapes, compiled = granite_decode
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 7  # q k v o gate up down
     d, kv, f = cfg.d_model, cfg.n_kv_heads * cfg.d_head, cfg.d_ff
@@ -189,6 +217,28 @@ def test_granite_decode_step_unpacks_weights_in_vmem(one_chip, monkeypatch):
         if shape not in stacked:  # the packed weights themselves
             assert np.prod(shape) < smallest_site, shape
     assert compiled.memory_analysis().temp_size_in_bytes < 235e6
+
+
+#: temporaries of the same compile with decode attention in the XLA chain
+#: (``_scores_int`` -> mask -> softmax -> ``_pv_int``)
+XLA_CHAIN_TEMP_BYTES = 32256
+
+
+def test_granite_decode_step_reads_cache_in_attention_kernel(granite_decode):
+    """Decode attention runs in ``decode_attn`` on the cache as it is stored:
+    no K row sums over the whole cache (``s32[8,4096,8]``, the XLA chain's
+    ``convert_reduce_fusion``), no copy of the K or V cache relaid out of
+    its row-major ``(B, T, kvH, dh)`` layout (the chain laid both out kv
+    head first), and no more temporaries than the chain's."""
+    _, _, compiled = granite_decode
+    text = compiled.as_text()
+    b, t, kvh, dh = (ATTN[k] for k in ("slots", "rows", "kv_heads", "dh"))
+    assert re.search(r"%decode_attn[.\d]* = .*tpu_custom_call", text)
+    assert f"s32[{b},{t},{kvh}]" not in text
+    cache = re.escape(f"s8[{b},{t},{kvh},{dh}]")
+    layouts = set(re.findall(cache + r"\{([0-9,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts
+    assert compiled.memory_analysis().temp_size_in_bytes <= XLA_CHAIN_TEMP_BYTES
 
 
 def test_deepseek_decode_step_reads_only_packed_experts(one_chip, monkeypatch):
