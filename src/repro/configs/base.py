@@ -220,15 +220,6 @@ class ArchConfig:
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
     quant: QuantConfig = QuantConfig()
-    # perf knobs (EXPERIMENTS.md §Perf): attention-score / logits compute
-    # dtypes — "f32" (baseline) or "bf16" (hillclimbed)
-    attn_scores_dtype: str = "f32"
-    logits_dtype: str = "f32"
-    # GQA layout: "grouped" contracts against un-expanded KV (best when
-    # n_kv_heads divides the model axis); "expand" repeats KV to H heads
-    # (best when kvH < |model|: the grouped (kvH, g) reshape of a 16-way
-    # sharded head dim triggers XLA involuntary full rematerialization).
-    gqa_mode: str = "grouped"
     mtp_depth: int = 0  # deepseek-v3 multi-token prediction heads
     max_seq: int = 131072
     source: str = ""  # provenance note: [source; verified-tier]

@@ -28,6 +28,7 @@ pipeline works, in seconds instead of hours.
 """
 
 import argparse
+import dataclasses
 import json
 import re
 import time
@@ -263,26 +264,20 @@ def lower_cell(cfg: ArchConfig, shape: InputShape, mesh, accum_steps: int = 1):
 
 
 OPT_TRANSFORMS = {
-    # §Perf hillclimb knobs — each is one hypothesis->change iteration
-    "scores_bf16": dict(attn_scores_dtype="bf16"),
-    "logits_bf16": dict(logits_dtype="bf16"),
-    "gqa_expand": dict(gqa_mode="expand"),
-    "packed_gather": "quant",  # binarize+pack before the FSDP all-gather
+    # binarize+pack QAT weights before the FSDP all-gather
+    "packed_gather": lambda cfg: dataclasses.replace(
+        cfg, quant=dataclasses.replace(cfg.quant, prebinarize_gather=True)
+    ),
 }
 
 
 def apply_opts(cfg: ArchConfig, opts) -> ArchConfig:
-    import dataclasses as _dc
-
     for o in opts or ():
         if o.startswith("accum"):
             continue  # handled by accum_steps
-        if o == "packed_gather":
-            cfg = _dc.replace(
-                cfg, quant=_dc.replace(cfg.quant, prebinarize_gather=True)
-            )
-            continue
-        cfg = _dc.replace(cfg, **OPT_TRANSFORMS[o])
+        if o not in OPT_TRANSFORMS:
+            raise ValueError(f"unknown opt {o!r}; have {sorted(OPT_TRANSFORMS)}")
+        cfg = OPT_TRANSFORMS[o](cfg)
     return cfg
 
 

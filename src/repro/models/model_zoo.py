@@ -256,8 +256,7 @@ def loss_fn(
     """
     tokens = batch["tokens"]
     hidden, aux = _forward_hidden(params, tokens, cfg, mode, batch.get("frontend"))
-    ldt = jnp.bfloat16 if cfg.logits_dtype == "bf16" else jnp.float32
-    logits = L.unembed(params, hidden, cfg.tie_embeddings, ldt)
+    logits = L.unembed(params, hidden, cfg.tie_embeddings)
     if cfg.causal:
         pred, tgt = logits[:, :-1], tokens[:, 1:]
     else:

@@ -186,10 +186,6 @@ def test_float_cache_takes_no_integer_core(tpu_choice):
     assert _qk_cores(cfg, "decode") == []
 
 
-def test_expand_mode_keeps_the_xla_chain(tpu_choice):
-    assert _qk_cores(_granite_heads(gqa_mode="expand"), "decode") == [("attn.qk", "xla")]
-
-
 def test_mla_latent_cache_keeps_the_xla_chain(tpu_choice):
     cfg = smoke_variant(get_config("deepseek-v2-lite-16b"))
     assert set(_qk_cores(cfg, "decode")) == {("attn.qk_latent", "xla")}

@@ -1,5 +1,7 @@
 """Serving engine + dry-run helper units (fast, no big compiles)."""
 
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -192,7 +194,10 @@ def test_opt_transforms_apply():
     from repro.launch.dryrun import apply_opts
 
     cfg = get_config("granite-8b")
-    out = apply_opts(cfg, ["scores_bf16", "gqa_expand", "packed_gather"])
-    assert out.attn_scores_dtype == "bf16"
-    assert out.gqa_mode == "expand"
+    out = apply_opts(cfg, ["packed_gather"])
     assert out.quant.prebinarize_gather
+    assert not cfg.quant.prebinarize_gather
+    assert dataclasses.replace(out, quant=cfg.quant) == cfg  # nothing else moved
+    assert apply_opts(cfg, ["accum4"]) == cfg  # accumulation is run_cell's
+    with pytest.raises(ValueError, match="unknown opt 'gqa_expand'"):
+        apply_opts(cfg, ["gqa_expand"])
